@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import (
     MACHINES, SCENARIOS, engine_names, explore_grid, select_schedule,
 )
@@ -67,7 +66,7 @@ x = jnp.asarray(rng.standard_normal((512, 256)), jnp.float32)  # M-sharded
 w = jnp.asarray(rng.standard_normal((256, 256)), jnp.float32)  # N-sharded
 
 fn = jax.jit(
-    shard_map(
+    jax.shard_map(
         functools.partial(
             ficco_linear, axis_name="tp", schedule=args.schedule,
             machine=machine,

@@ -13,7 +13,7 @@ pipeline scan, all expressed as pure array math over a
     into a few Adam steps (:func:`calibrate_tau`) instead of a discrete
     candidate search.
 
-Numerics: the engine runs in float64 (``jax.experimental.enable_x64``
+Numerics: the engine runs in float64 (``jax.enable_x64``
 scoped to this module's entry points — the global x64 flag is never
 touched) and replays the NumPy engine's accumulation order, so grids
 agree with ``repro.core.batch.evaluate_grid`` to ~1e-12 relative, far
@@ -45,7 +45,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core import inefficiency as ineff
 from repro.core.batch import (
@@ -815,7 +814,7 @@ def evaluate_ragged_grid_raw(
     recompile.
     """
     rb = _as_ragged_batch(scenarios)
-    with enable_x64():
+    with jax.enable_x64(True):
         if isinstance(machines_or_arrays, MachineArrays):
             mp = machines_or_arrays
             if g_max is None:
@@ -893,7 +892,7 @@ def evaluate_grid_raw(
     ``closed_form=True`` selects :func:`pipeline_closed_jax` (totals
     equal to the scan up to rounding; the device sweep fast path).
     """
-    with enable_x64():
+    with jax.enable_x64(True):
         if isinstance(machines_or_arrays, MachineArrays):
             mp = machines_or_arrays
             if g_max is None:
@@ -996,7 +995,7 @@ def expected_heuristic_time(
     per-scenario optimum.  ``d(this)/d(tau)`` is finite and nonzero —
     the gradient signal :func:`calibrate_tau` descends.
     """
-    with enable_x64():
+    with jax.enable_x64(True):
         if _precomputed is None:
             _precomputed = _tau_loss_inputs(scenarios, machine)
         m, k, flops, t_norm, peak, hard = _precomputed
@@ -1018,7 +1017,7 @@ def _tau_loss_inputs(scenarios, machine: MachineSpec):
     gate_scores = serial_gate_score_batch(
         sb.m, sb.n, sb.k, sb.dtype_bytes, machine
     )
-    with enable_x64():
+    with jax.enable_x64(True):
         m, n, k, b = scenario_arrays(sb)
         flops = 2.0 * (m * n).astype(_F) * k
         best = jnp.min(jnp.where(valid, total, jnp.inf), axis=0)
@@ -1060,7 +1059,7 @@ def calibrate_tau_reference(
     pre = _tau_loss_inputs(scenarios, machine)
     m, k, flops, t_norm, peak, hard = pre
 
-    with enable_x64():
+    with jax.enable_x64(True):
         taus = np.geomspace(lo, hi, 512)
         losses = np.array([
             float(_tau_loss(jnp.log(jnp.asarray(t, dtype=_F)),
@@ -1109,7 +1108,7 @@ def calibrate_tau(
     pre = _tau_loss_inputs(scenarios, machine)
     m, k, flops, t_norm, peak, hard = pre
 
-    with enable_x64():
+    with jax.enable_x64(True):
         grad_fn = jax.jit(
             jax.value_and_grad(
                 lambda lt: _tau_loss(

@@ -460,7 +460,7 @@ class Autotuner:
             # answers instead.
             import jax as _jax
 
-            if not _jax.core.trace_state_clean():
+            if not _jax.core.trace_ctx.is_top_level():
                 eng = _engine.get_engine("numpy")
         with _trace.span(
             "tuner/shortlist", "autotune", engine=eng.name, top=top
@@ -497,7 +497,6 @@ class Autotuner:
         import numpy as np
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
         from repro.overlap.api import _divisible
         from repro.overlap.schedules import SCHEDULE_FNS
 
@@ -523,7 +522,7 @@ class Autotuner:
         timings: dict[Schedule, float] = {}
         for sched in candidates:
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     functools.partial(
                         SCHEDULE_FNS[sched], axis_name=axis_name
                     ),

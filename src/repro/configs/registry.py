@@ -151,7 +151,7 @@ def smollm_360m() -> ModelConfig:
         d_ff=2560,
         vocab_size=49152,
         tie_embeddings=True,
-        citation="hf:HuggingFaceTB/SmolLM-135M",
+        citation="hf:HuggingFaceTB/SmolLM-360M",
     )
 
 

@@ -28,12 +28,31 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import (
-    axis_size,
-    remote_device_id,
-    tpu_compiler_params,
-    tpu_interpret,
-)
+# Each communicating kernel owns a distinct collective id: it names the
+# barrier semaphore the kernel handshakes on (``peer_barrier``).
+EXCHANGE_COLLECTIVE_ID = 0
+
+
+def interpret_params(interpret: bool):
+    """``interpret=`` for a DMA kernel: the Mosaic TPU interpreter, which
+    simulates cross-device DMAs and semaphores, or compiled Mosaic."""
+    return pltpu.InterpretParams() if interpret else False
+
+
+def peer_barrier(me, group: int, axis_name: str) -> None:
+    """Handshake with every peer on ``axis_name`` before any remote write.
+
+    A remote copy lands in the peer's output buffer, which XLA may still
+    be using for an earlier op until the peer has entered this kernel.
+    Each device signals the barrier semaphore of all g-1 peers, then
+    waits for their g-1 signals, leaving the semaphore at zero.
+    """
+    sem = pltpu.get_barrier_semaphore()
+    for i in range(1, group):
+        pltpu.semaphore_signal(
+            sem, 1, device_id={axis_name: lax.rem(me + i, group)}
+        )
+    pltpu.semaphore_wait(sem, group - 1)
 
 
 def _exchange_kernel(
@@ -63,18 +82,17 @@ def _exchange_kernel(
         chunk_ref, out_ref.at[me], recv_sems.at[group - 1]
     )
     local.start()
+    peer_barrier(me, group, axis_name)
 
     copies = []
     for i in range(1, group):
         peer = lax.rem(me + (group - i if reverse else i), group)
-        device_id, id_type = remote_device_id(peer)
         rc = pltpu.make_async_remote_copy(
             src_ref=chunk_ref,
             dst_ref=out_ref.at[me],
             send_sem=send_sems.at[i - 1],
             recv_sem=recv_sems.at[i - 1],
-            device_id=device_id,
-            device_id_type=id_type,
+            device_id={axis_name: peer},
         )
         rc.start()
         copies.append(rc)
@@ -112,9 +130,9 @@ def a2a_chunk_exchange(
             pltpu.SemaphoreType.DMA((group - 1,)),
             pltpu.SemaphoreType.DMA((group,)),
         ],
-        interpret=tpu_interpret(interpret),
-        compiler_params=tpu_compiler_params(
-            collective_id=0, has_side_effects=True
+        interpret=interpret_params(interpret),
+        compiler_params=pltpu.CompilerParams(
+            collective_id=EXCHANGE_COLLECTIVE_ID, has_side_effects=True
         ),
     )(chunk)
 
@@ -141,7 +159,7 @@ def ficco_uniform_fused_1d_dma(
     dispatch order; ``None`` resolves the promoted default from
     :mod:`repro.tune.registry`.
     """
-    g = axis_size(axis_name)
+    g = lax.axis_size(axis_name)
     m_s, k = x.shape
     n_local = w.shape[1]
     if variant is None:

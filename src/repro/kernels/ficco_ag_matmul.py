@@ -17,9 +17,13 @@ This removes the Gather/Scatter streams that give uniform-fused-1D its HIGH
 CIL signature — measured in EXPERIMENTS.md §Perf as the `dma_into_place`
 optimization.
 
-Layout: x shard (m_s, K) split into g chunks of (m_c, K); w (K, n_local) is
-brought into VMEM tile by tile for the step GEMM; outputs are the
-(M = g*m_s, n_local) rows this device owns after the gather.
+Layout: x shard (m_s, K) split into ``steps`` chunks of (m_c, K); the
+(K, n_local) weight panel stays resident in VMEM for every step GEMM;
+outputs are the (M = g*m_s, n_local) rows this device owns after the
+gather.  The kernel requests its scoped VMEM explicitly
+(``VMEM_LIMIT_BYTES``): the compiler's default limit is far below what
+prefill-sized steps need.  ``repro.tune.prune`` budgets variants against
+the same figure with the same footprint (``fused_vmem_bytes``).
 """
 
 from __future__ import annotations
@@ -32,13 +36,56 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import (
-    axis_size,
-    remote_device_id,
-    remote_semaphore_signal,
-    tpu_compiler_params,
-    tpu_interpret,
-)
+from repro.kernels.dma_exchange import interpret_params, peer_barrier
+
+FUSED_COLLECTIVE_ID = 1
+# Scoped VMEM the kernel requests: half of a v5e core's 128 MiB, four
+# times the compiler's default limit.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def fused_steps(m_s: int, group: int, variant) -> tuple[int, int]:
+    """(steps, depth) the fused kernel runs ``variant`` with on a shard of
+    ``m_s`` rows: the variant's chunk count, or the classic g-way cut when
+    that does not divide the shard."""
+    steps = int(variant.chunks)
+    if m_s % steps:
+        steps = group
+    depth = max(2, min(int(variant.buffer_depth), steps))
+    return steps, depth
+
+
+LANES = 128
+
+
+def padded_cols(n_local: int) -> int:
+    """Weight/output columns the kernel allocates: ``n_local`` rounded up
+    to whole 128-lane tiles, since Mosaic slices and DMAs VMEM only on
+    lane-aligned widths (TP=8 TinyLlama has n_local = 704)."""
+    return -(-n_local // LANES) * LANES
+
+
+def col_tile(n_cols: int) -> int:
+    """Output columns one step-GEMM iteration produces: the widest
+    lane-aligned tile that divides the padded width ``n_cols``."""
+    for bn in (512, 256):
+        if n_cols % bn == 0:
+            return bn
+    return LANES
+
+
+def fused_vmem_bytes(
+    group: int, m_c: int, k: int, n_local: int, depth: int, itemsize: int
+) -> int:
+    """VMEM one kernel instance allocates: ``depth`` inbound (g*m_c, K)
+    step slots, the (K, n) weight panel, ``depth`` outbound (g*m_c, n)
+    slots, and one f32 (g*m_c, col_tile) product, n = padded n_local."""
+    rows = group * m_c
+    n = padded_cols(n_local)
+    return (
+        itemsize * (depth * rows * k + k * n + depth * rows * n)
+        + 4 * rows * col_tile(n)
+    )
 
 
 def _fused_kernel(
@@ -48,20 +95,20 @@ def _fused_kernel(
     depth: int,
     reverse: bool,
     m_c: int,
-    k: int,
     n_local: int,
     x_ref,  # (steps, m_c, K) local chunks, ANY/HBM
     w_ref,  # (K, n_local), ANY/HBM
-    o_ref,  # (steps, g, m_c, n_local): [step, src] output blocks, ANY/HBM
-    step_bufs,  # VMEM (depth, g, m_c, K): slot-buffered gathered steps
+    o_ref,  # (steps, g*m_c, n_local): step s, rows of source d at d*m_c
+    step_bufs,  # VMEM (depth, g*m_c, K): slot-buffered gathered steps
     w_vmem,  # VMEM (K, n_local)
-    out_vmem,  # VMEM (depth, g, m_c, n_local): slot-buffered egress staging
+    out_vmem,  # VMEM (depth, g*m_c, n_local): slot-buffered egress staging
     send_sems,  # DMA (depth, g-1)
     recv_sems,  # DMA (depth, g)
     out_sems,  # DMA (depth,): per-slot output egress
     ready_sems,  # REGULAR (depth,): receiver->sender slot flow control
 ):
     me = lax.axis_index(axis_name)
+    bn = col_tile(n_local)
 
     # Dispatch order: which chunk each pipeline position carries.  Output
     # blocks are indexed by the chunk id, so reversing the issue order
@@ -86,23 +133,19 @@ def _fused_kernel(
         """
         if wait_slot:
             pltpu.semaphore_wait(ready_sems.at[slot], group - 1)
+        mine = step_bufs.at[slot, pl.ds(me * m_c, m_c)]
         local = pltpu.make_async_copy(
-            x_ref.at[s],
-            step_bufs.at[slot, me],
-            recv_sems.at[slot, group - 1],
+            x_ref.at[s], mine, recv_sems.at[slot, group - 1]
         )
         local.start()
         descs = [local]
         for i in range(1, group):
-            peer = lax.rem(me + i, group)
-            device_id, id_type = remote_device_id(peer)
             rc = pltpu.make_async_remote_copy(
                 src_ref=x_ref.at[s],
-                dst_ref=step_bufs.at[slot, me],
+                dst_ref=mine,
                 send_sem=send_sems.at[slot, i - 1],
                 recv_sem=recv_sems.at[slot, i - 1],
-                device_id=device_id,
-                device_id_type=id_type,
+                device_id={axis_name: lax.rem(me + i, group)},
             )
             rc.start()
             descs.append(rc)
@@ -118,13 +161,31 @@ def _fused_kernel(
     def release_slot(slot: int):
         """Tell every peer our copy of this slot is consumed."""
         for i in range(1, group):
-            peer = lax.rem(me + i, group)
-            remote_semaphore_signal(ready_sems.at[slot], 1, peer)
+            pltpu.semaphore_signal(
+                ready_sems.at[slot], 1,
+                device_id={axis_name: lax.rem(me + i, group)},
+            )
+
+    def step_gemm(slot: int):
+        """out_vmem[slot] = step_bufs[slot] @ w, one column tile per
+        iteration so the kernel's code stays one tile's worth."""
+
+        def tile(j, carry):
+            col = pl.multiple_of(j * bn, bn)
+            out_vmem[slot, :, pl.ds(col, bn)] = jnp.dot(
+                step_bufs[slot],
+                w_vmem[:, pl.ds(col, bn)],
+                preferred_element_type=jnp.float32,
+            ).astype(out_vmem.dtype)
+            return carry
+
+        lax.fori_loop(0, n_local // bn, tile, 0)
 
     w_copy.wait()
+    peer_barrier(me, group, axis_name)
     inflight = start_step(order[0], 0, False)
-    # Output egress is slot-buffered like the ingress: a position's (g,
-    # m_c, n_local) block drains to HBM while later positions' exchange
+    # Output egress is slot-buffered like the ingress: a position's
+    # (g*m_c, n_local) block drains to HBM while later positions' exchange
     # and matmul proceed.  A slot is only rewritten after its previous
     # drain (``depth`` positions earlier) completed — without that wait a
     # fast MXU could clobber bytes the DMA engine is still reading.
@@ -132,24 +193,18 @@ def _fused_kernel(
     for pos, s in enumerate(order):
         slot = pos % depth
         wait_step(inflight)
-        # Load (consume) the gathered buffer, release the slot to peers,
-        # kick off the next exchange, THEN multiply — so the next
-        # position's DMAs fly while the MXU works on this one.
-        gathered = step_bufs[slot].reshape(group * m_c, k)
-        if pos + depth < steps:
-            release_slot(slot)
+        # Kick off the next exchange, THEN multiply — so the next
+        # position's DMAs fly while the MXU works on this one — and only
+        # then release this slot to the peers that will refill it.
         if pos + 1 < steps:
             inflight = start_step(
                 order[pos + 1], (pos + 1) % depth, pos + 1 >= depth
             )
-        step_out = jnp.dot(
-            gathered, w_vmem[...], preferred_element_type=jnp.float32
-        )
         if out_copies[slot] is not None:
             out_copies[slot].wait()
-        out_vmem[slot] = step_out.reshape(group, m_c, n_local).astype(
-            out_vmem.dtype
-        )
+        step_gemm(slot)
+        if pos + depth < steps:
+            release_slot(slot)
         out_copy = pltpu.make_async_copy(
             out_vmem.at[slot], o_ref.at[s], out_sems.at[slot]
         )
@@ -170,59 +225,66 @@ def ficco_ag_matmul_fused(
 ) -> jax.Array:
     """Fused uniform-fused-1D: returns (M, n_local) like the reference.
 
-    Call inside shard_map over ``axis_name``.  VMEM budget: the step buffer
-    slots (depth * m_s/steps * g * K), the weight panel (K * n_local) and
-    the slot-buffered per-step output must fit VMEM — production shapes
-    tile K/N further; sizes used in tests and smoke configs fit
-    comfortably.
+    Call inside shard_map over ``axis_name``.  VMEM: ``fused_vmem_bytes``
+    at the plan ``fused_steps`` picks must fit ``VMEM_LIMIT_BYTES``, which
+    ``repro.tune.prune`` checks before a variant reaches the compiler.
 
     ``variant`` (a :class:`repro.tune.KernelVariant`) picks the chunk
     count, DMA buffer depth and dispatch order; ``None`` resolves the
     promoted default from :mod:`repro.tune.registry`.  Results are
     bit-identical across variants: each output row is one full-K dot.
     """
-    g = axis_size(axis_name)
+    g = lax.axis_size(axis_name)
     m_s, k = x.shape
-    n_local = w.shape[1]
+    n_out = w.shape[1]
+    n_local = padded_cols(n_out)
+    if n_local != n_out:  # zero columns; the real ones are unchanged
+        w = jnp.pad(w, ((0, 0), (0, n_local - n_out)))
     if variant is None:
         from repro.tune.registry import resolve_variant
 
         variant = resolve_variant("ficco_ag_matmul", group=g)
-    steps = int(variant.chunks)
-    if m_s % steps:
-        steps = g  # promoted cut doesn't divide this shard; classic cut
-    depth = max(2, min(int(variant.buffer_depth), steps))
+    steps, depth = fused_steps(m_s, g, variant)
     reverse = variant.dispatch_order == "reverse"
     m_c = m_s // steps
     chunks = x.reshape(steps, m_c, k)
     kernel = functools.partial(
-        _fused_kernel, g, axis_name, steps, depth, reverse, m_c, k, n_local
+        _fused_kernel, g, axis_name, steps, depth, reverse, m_c, n_local
     )
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((steps, g, m_c, n_local), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((steps, g * m_c, n_local), x.dtype),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM((depth, g, m_c, k), x.dtype),
+            pltpu.VMEM((depth, g * m_c, k), x.dtype),
             pltpu.VMEM((k, n_local), w.dtype),
-            pltpu.VMEM((depth, g, m_c, n_local), x.dtype),
+            pltpu.VMEM((depth, g * m_c, n_local), x.dtype),
             pltpu.SemaphoreType.DMA((depth, g - 1)),
             pltpu.SemaphoreType.DMA((depth, g)),
             pltpu.SemaphoreType.DMA((depth,)),
             pltpu.SemaphoreType.REGULAR((depth,)),
         ],
-        interpret=tpu_interpret(interpret),
-        compiler_params=tpu_compiler_params(
-            collective_id=1, has_side_effects=True
+        interpret=interpret_params(interpret),
+        compiler_params=pltpu.CompilerParams(
+            collective_id=FUSED_COLLECTIVE_ID,
+            has_side_effects=True,
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
     )(chunks, w)
-    # out[s, d] = rows of source d, step s -> global row d*m_s + s*m_c.
-    out = out.transpose(1, 0, 2, 3)  # (src, step, m_c, n)
-    return out.reshape(g * m_s, n_local)
+    # out[s, d*m_c:] = rows of source d, step s -> global row d*m_s + s*m_c.
+    out = out.reshape(steps, g, m_c, n_local).transpose(1, 0, 2, 3)
+    return out.reshape(g * m_s, n_local)[:, :n_out]
 
 
-__all__ = ["ficco_ag_matmul_fused"]
+__all__ = [
+    "VMEM_LIMIT_BYTES",
+    "col_tile",
+    "ficco_ag_matmul_fused",
+    "padded_cols",
+    "fused_steps",
+    "fused_vmem_bytes",
+]

@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True automatically on non-TPU backends so the
-same call sites work on this CPU container (Mosaic interpreter) and on real
-TPUs (compiled Mosaic).
+The backend decides how a kernel runs: compiled Mosaic on a TPU, the
+Mosaic interpreter on the CPU (tests and small-size rehearsals).  Any
+other backend is an error rather than a silent switch to the interpreter.
 """
 
 from __future__ import annotations
@@ -19,8 +19,17 @@ from repro.kernels.dma_exchange import (
 from repro.kernels.ficco_ag_matmul import ficco_ag_matmul_fused
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """True on the CPU backend, False on a TPU; raises on anything else."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels need a TPU or the CPU interpreter; "
+        f"the default backend is {backend!r}"
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k"))
@@ -28,7 +37,7 @@ def matmul(x, w, *, block_m=128, block_n=128, block_k=128):
     return chunked_matmul(
         x, w,
         block_m=block_m, block_n=block_n, block_k=block_k,
-        interpret=not _on_tpu(),
+        interpret=_interpret(),
     )
 
 
@@ -37,28 +46,28 @@ def matmul_accumulate(c, x, w, *, block_m=128, block_n=128, block_k=128):
     return accumulate_matmul(
         c, x, w,
         block_m=block_m, block_n=block_n, block_k=block_k,
-        interpret=not _on_tpu(),
+        interpret=_interpret(),
     )
 
 
 def chunk_exchange(chunk, *, axis_name, group):
     """shard_map-internal: DMA all-to-all of one FiCCO chunk."""
     return a2a_chunk_exchange(
-        chunk, axis_name=axis_name, group=group, interpret=not _on_tpu()
+        chunk, axis_name=axis_name, group=group, interpret=_interpret()
     )
 
 
 def ag_matmul_dma(x, w, *, axis_name):
     """shard_map-internal: uniform-fused-1D with Pallas DMA comm."""
     return ficco_uniform_fused_1d_dma(
-        x, w, axis_name=axis_name, interpret=not _on_tpu()
+        x, w, axis_name=axis_name, interpret=_interpret()
     )
 
 
 def ag_matmul_fused(x, w, *, axis_name):
     """shard_map-internal: fully fused DMA+MXU pipeline (beyond-paper)."""
     return ficco_ag_matmul_fused(
-        x, w, axis_name=axis_name, interpret=not _on_tpu()
+        x, w, axis_name=axis_name, interpret=_interpret()
     )
 
 

@@ -31,7 +31,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.compat import set_mesh as _set_mesh  # noqa: E402
 from repro.configs import ARCHS, SHAPES, get_config  # noqa: E402
 from repro.configs.base import ModelConfig, ShapeConfig  # noqa: E402
 from repro.launch import specs as specmod  # noqa: E402
@@ -171,7 +170,7 @@ def _build_jitted(cfg, shape, mesh, accum_steps: int = 1):
 
 def _compile(cfg, shape, mesh):
     jitted, args, _ = _build_jitted(cfg, shape, mesh)
-    with _set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         with overlap_context(cfg.overlap):
             lowered = jitted.lower(*args)
         compiled = lowered.compile()
@@ -240,7 +239,7 @@ def dryrun_one(
     chips = mesh.size
     t0 = time.time()
     jitted, args, _ = _build_jitted(cfg, shape, mesh, accum_steps)
-    with _set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         with overlap_context(cfg.overlap):
             lowered = jitted.lower(*args)
         t_lower = time.time() - t0

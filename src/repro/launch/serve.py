@@ -1,8 +1,12 @@
-"""Serving launcher: batched greedy decoding with the reduced model.
+"""Serving launcher: batched greedy decoding.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
-      --prompts 4 --new-tokens 16 [--overlap-mode ficco_autotune]
+      --prompts 4 --new-tokens 16 [--full-size] \
+      [--overlap-mode ficco_autotune]
+
+The model is the reduced variant (2 layers, float32) unless
+``--full-size`` asks for the published config (bfloat16).
 
 ``--overlap-mode ficco_autotune`` selects TP overlap schedules through
 the persistent runtime autotuner (repro.autotune) — serving processes
@@ -26,6 +30,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, get_config
+from repro.launch.cache import use_compile_cache
 from repro.models.model import build_model
 from repro.serve.engine import DecodeEngine, Request
 
@@ -37,6 +42,9 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--cache-len", type=int, default=128)
+    ap.add_argument("--full-size", action="store_true",
+                    help="serve the published config (bfloat16) instead "
+                    "of the reduced variant")
     ap.add_argument(
         "--overlap-mode", default="gspmd_serial",
         help="gspmd_serial | serial | shard_p2p | ficco_auto | "
@@ -66,7 +74,10 @@ def main():
 
         _signature.enable_signatures(args.signatures)
 
-    cfg = get_config(args.arch).reduced()
+    use_compile_cache()
+    cfg = get_config(args.arch)
+    if not args.full_size:
+        cfg = cfg.reduced()
     if args.overlap_mode != "gspmd_serial":
         cfg = dataclasses.replace(
             cfg,
@@ -109,8 +120,10 @@ def main():
     out = eng.run(reqs)
     dt = time.time() - t0
     total = sum(len(r.out) for r in out)
+    dev = jax.devices()[0]
     print(f"decoded {total} tokens in {dt:.2f}s "
-          f"({total / dt:.1f} tok/s on CPU interpret)")
+          f"({total / dt:.1f} tok/s on {dev.platform} {dev.device_kind}, "
+          f"compile included)")
     if tier is not None:
         dec = eng.last_decision
         sched = dec.schedule.value if dec is not None else "-"

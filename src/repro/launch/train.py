@@ -23,6 +23,7 @@ import dataclasses
 
 from repro.configs import ARCHS, get_config
 from repro.configs.base import ShapeConfig
+from repro.launch.cache import use_compile_cache
 from repro.train.loop import train
 from repro.train.optimizer import OptimizerConfig
 
@@ -46,6 +47,7 @@ def main():
                     "bound; intended for cluster runs")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if not args.full_size:
         cfg = cfg.reduced()

@@ -250,12 +250,12 @@ class FitResult:
 
 
 def _patched_arrays(machine: MachineSpec, overrides: dict[str, float]):
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.autotune.jaxgrid import machine_arrays
 
-    with enable_x64():
+    with jax.enable_x64(True):
         mp = machine_arrays((machine,))
         return mp._replace(
             **{
@@ -290,7 +290,6 @@ def fit_machine(
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.autotune.jaxgrid import (
         evaluate_grid_raw,
@@ -334,7 +333,7 @@ def fit_machine(
     targets = np.log(np.asarray([r.seconds for r in records]))
     eval_raw = evaluate_ragged_grid_raw if ragged else evaluate_grid_raw
 
-    with enable_x64():
+    with jax.enable_x64(True):
         mp0 = machine_arrays((eff,))
         init = {
             name: float(np.asarray(getattr(mp0, name))[0]) for name in params
@@ -404,8 +403,8 @@ def synthesize_records(
 ) -> list[MeasuredRecord]:
     """Model-generated "measured" times, optionally from a perturbed
     machine — the synthetic ground truth the fit tests recover."""
+    import jax
     import jax.numpy as jnp  # noqa: F401 — jax presence check
-    from jax.experimental import enable_x64
 
     from repro.autotune.jaxgrid import evaluate_grid_raw
     from repro.core.batch import ScenarioBatch
@@ -413,7 +412,7 @@ def synthesize_records(
 
     mp = _patched_arrays(machine, overrides or {})
     sb = ScenarioBatch.from_gemms(gemms)
-    with enable_x64():
+    with jax.enable_x64(True):
         out = evaluate_grid_raw(sb, mp, g_max=machine.group)
         total = np.asarray(out[0][0])  # (L, S)
         valid = np.asarray(out[5][0])
@@ -464,8 +463,8 @@ class FittedEngine:
         dma_into_place: bool = False,
         schedules=None,
     ):
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         from repro.autotune.jaxgrid import (
             evaluate_grid_raw,
@@ -495,7 +494,7 @@ class FittedEngine:
             j for j, mch in enumerate(machines)
             if mch.name == self.fit.machine
         ]
-        with enable_x64():
+        with jax.enable_x64(True):
             mp = machine_arrays(machines)
             for name, val in self.fit.fitted.items():
                 arr = getattr(mp, name)

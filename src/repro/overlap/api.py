@@ -10,8 +10,9 @@ consults :func:`repro.core.heuristics.select_schedule` with the *static*
 global GEMM dimensions — no profiling — and dispatches the chosen schedule.
 ``schedule="autotune"`` goes one step further: it consults the process-wide
 :class:`repro.autotune.Autotuner` (persistent cache -> jitted analytic
-model -> optional measured shortlist) and falls back to the static
-heuristic if the tuner cannot answer.
+model -> optional measured shortlist).  When the tuner's model cannot rank
+the shape, the tuner itself answers with the static heuristic and the
+resolution is counted as ``overlap/resolve.autotune_fallback``.
 """
 
 from __future__ import annotations
@@ -19,8 +20,8 @@ from __future__ import annotations
 from typing import Union
 
 import jax
+from jax import lax
 
-from repro.compat import axis_size
 from repro.core.heuristics import select_schedule
 from repro.core.machine import TPU_V5E, MachineSpec, machine_for_group
 from repro.core.schedule_types import Schedule
@@ -62,16 +63,16 @@ def resolve_schedule(
         if group:
             eff = machine_for_group(eff, group)
         if schedule == "autotune":
-            gemm = GemmShape(m, n, k, dtype_bytes)
-            try:
-                from repro.autotune import get_tuner  # keep import lazy
+            from repro.autotune import get_tuner  # keep import lazy
 
-                sched = get_tuner().pick(gemm, machine, group=group).schedule
-                return _resolved("autotune", sched, sp)
-            except Exception:
-                # Zero-cost fallback: the static decision tree.
-                sched = select_schedule(gemm, eff).schedule
-                return _resolved("autotune_fallback", sched, sp)
+            dec = get_tuner().pick(
+                GemmShape(m, n, k, dtype_bytes), machine, group=group
+            )
+            # The tuner answers "heuristic" when its model could not rank
+            # the shape; that decision is the static tree's, so count it.
+            fallback = dec.source == "heuristic"
+            how = "autotune_fallback" if fallback else "autotune"
+            return _resolved(how, dec.schedule, sp)
         if schedule != "auto":
             return _resolved("named", Schedule(schedule), sp)
         dec = select_schedule(GemmShape(m, n, k, dtype_bytes), eff)
@@ -109,7 +110,7 @@ def ficco_linear(
     Returns:
       (M, N/g): the full gathered-M rows times this device's weight columns.
     """
-    g = axis_size(axis_name)
+    g = lax.axis_size(axis_name)
     m_s, k = x.shape
     n_local = w.shape[1]
     sched = resolve_schedule(

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size
 
 
 def _ffn(x: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
@@ -47,7 +46,7 @@ def serial_a2a_ffn(
     x: (E, C, D) tokens grouped by destination expert (E global experts,
     E = g * E_local).  Returns (E, C, D) tokens back in source layout.
     """
-    g = axis_size(axis_name)
+    g = lax.axis_size(axis_name)
     e, c, d = x.shape
     e_local = e // g
     # dispatch: split expert dim over devices, concat source dim.
@@ -108,7 +107,7 @@ def ficco_a2a_ffn(
     mass) while outputs are still reassembled in capacity order, so
     results are bit-identical across variants.
     """
-    g = axis_size(axis_name)
+    g = lax.axis_size(axis_name)
     e, c, d = x.shape
     if variant is None and chunks is None and chunk_sizes is None:
         from repro.tune.registry import resolve_variant

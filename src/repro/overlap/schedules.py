@@ -41,12 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size
 from repro.core.schedule_types import Schedule
-
-
-def _axis_size(axis_name: str) -> int:
-    return axis_size(axis_name)
 
 
 def _my_index(axis_name: str):
@@ -72,7 +67,7 @@ def shard_p2p_matmul(
     (``lax.ppermute`` — a single P2P link per step, the topology weakness
     FiCCO fixes) while computing the GEMM on the shard already held.
     """
-    g = _axis_size(axis_name)
+    g = lax.axis_size(axis_name)
     me = _my_index(axis_name)
     m_s, _ = x.shape
     n_local = w.shape[1]
@@ -108,7 +103,7 @@ def ficco_uniform_fused_1d(
     """uniform-fused-1D: g steps; step s exchanges chunk s with all peers
     (all-to-all shaped), Gathers local+remote into one buffer, runs ONE
     identical (M/g, N_local, K) GEMM, and Scatters the output rows."""
-    g = _axis_size(axis_name)
+    g = lax.axis_size(axis_name)
     m_s, k = x.shape
     n_local = w.shape[1]
     m_c = m_s // g
@@ -137,7 +132,7 @@ def ficco_hetero_fused_1d(
     """hetero-fused-1D: compute the whole local shard immediately (hiding
     the first exposed exchange), then per step one fused GEMM over the g-1
     *remote* chunks received in that step."""
-    g = _axis_size(axis_name)
+    g = lax.axis_size(axis_name)
     me = _my_index(axis_name)
     m_s, k = x.shape
     n_local = w.shape[1]
@@ -171,7 +166,7 @@ def ficco_hetero_unfused_1d(
 ) -> jax.Array:
     """hetero-unfused-1D: like hetero-fused but one GEMM *per chunk* —
     no Gather at all, maximum scheduling freedom, highest DIL."""
-    g = _axis_size(axis_name)
+    g = lax.axis_size(axis_name)
     me = _my_index(axis_name)
     m_s, k = x.shape
     n_local = w.shape[1]
@@ -200,7 +195,7 @@ def ficco_uniform_fused_2d(
     full-M (M, K/g) panel and runs an accumulating GEMM C += panel @ w_slice.
     Output rows are contiguous — no Scatter; requires accumulation instead.
     """
-    g = _axis_size(axis_name)
+    g = lax.axis_size(axis_name)
     m_s, k = x.shape
     n_local = w.shape[1]
     if k % g:

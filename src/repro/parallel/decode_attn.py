@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.parallel.sharding import BATCH_AXES, MODEL_AXIS, _active_mesh
 
 _NEG_INF = -1e30
@@ -104,7 +103,7 @@ def shard_map_attn_decode(
 
     rep_spec = P(bspec, None, None, None)
     cache_spec = P(bspec, MODEL_AXIS, None, None)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(rep_spec, rep_spec, rep_spec, cache_spec, cache_spec,

@@ -21,21 +21,9 @@ MODEL_AXIS = "model"
 
 
 def _active_mesh():
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        mesh = None  # old JAX: no abstract-mesh API; try the physical mesh
-    if mesh is None or not mesh.shape:
-        # fall back to the concrete mesh context if one is entered
-        try:
-            from jax.interpreters import pxla
-
-            mesh = pxla.thread_resources.env.physical_mesh
-            if mesh.empty:
-                return None
-        except Exception:
-            return None
-    return mesh
+    """The mesh installed by ``jax.sharding.set_mesh``, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _filter_spec(spec: P, axis_names) -> P:
@@ -72,10 +60,7 @@ def constrain(x: jax.Array, *spec_entries) -> jax.Array:
         size = _axis_size(mesh, e)
         if size <= 1 or x.shape[i] % size:
             entries[i] = None
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*entries))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*entries))
 
 
 def batch_spec(*rest) -> tuple:

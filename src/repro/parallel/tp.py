@@ -13,7 +13,9 @@ Modes (config.overlap.mode):
     repro.overlap ("ficco_autotune" consults the persistent runtime
     tuner in repro.autotune, falling back to the static heuristic).
 Backend "pallas_dma" swaps the chunk exchange for the Pallas ICI-DMA
-kernel (repro.kernels) — the paper's DMA offload made explicit.
+kernel (repro.kernels) — the paper's DMA offload made explicit.  Each
+such linear counts the path that served it (``tp/pallas_dma.dma`` or
+``tp/pallas_dma.xla``), so a run can assert the kernel really ran.
 """
 
 from __future__ import annotations
@@ -23,10 +25,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import OverlapConfig
 from repro.core.machine import TPU_V5E
 from repro.core.schedule_types import Schedule
+from repro.obs import metrics as _metrics
 from repro.overlap.api import ficco_linear
 from repro.parallel.sharding import BATCH_AXES, MODEL_AXIS, _active_mesh
 
@@ -73,9 +75,16 @@ def tp_ficco_linear(
         # device-major concatenation reconstructs the global seq order.
         b_local = x_shard.shape[0]
         rows = x_shard.transpose(1, 0, 2).reshape(-1, d)  # (S/g*B, D)
-        if overlap.backend == "pallas_dma" and schedule in (
+        use_dma = schedule in (
             "auto", Schedule.UNIFORM_FUSED_1D.value
-        ) and rows.shape[0] % g == 0:
+        ) and rows.shape[0] % g == 0
+        if overlap.backend == "pallas_dma":
+            # Trace-time count of which path served each DMA-backend
+            # linear: a schedule or shape the kernel cannot take runs the
+            # XLA collectives instead.
+            path = "dma" if use_dma else "xla"
+            _metrics.get_metrics().counter(f"tp/pallas_dma.{path}").inc()
+        if overlap.backend == "pallas_dma" and use_dma:
             from repro.kernels.ops import ag_matmul_dma
 
             out = ag_matmul_dma(rows, w_shard, axis_name=MODEL_AXIS)
@@ -92,7 +101,7 @@ def tp_ficco_linear(
 
     batch_axes = tuple(a for a in BATCH_AXES if a in mesh.shape)
     bspec = batch_axes if batch_axes else None
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(bspec, MODEL_AXIS, None), P(None, MODEL_AXIS)),

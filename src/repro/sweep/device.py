@@ -261,10 +261,10 @@ def device_batch(
     The materialized form exists for parity tests and engine reuse; the
     fused sweep (:func:`sweep_device_stats`) never leaves the device.
     """
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with jax.enable_x64(True):
         lane = _lanes(jnp, n, np.uint64(start))
         m, nn, kk, b = _synth_uniform(jnp, lane, seed, dtype_bytes)
         return ScenarioBatch(
@@ -283,12 +283,12 @@ def device_ragged_batch(
     dtype_bytes=(2, 1),
 ) -> RaggedBatch:
     """On-device ragged synthesis, materialized as a RaggedBatch."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     if steps < 1:
         raise ValueError(f"steps must be >= 1, got {steps}")
-    with enable_x64():
+    with jax.enable_x64(True):
         lane = _lanes(jnp, n, np.uint64(start))
         m, nn, kk, b = _synth_uniform(jnp, lane, seed, dtype_bytes)
         frac = _synth_frac(jnp, lane, seed, steps, concentration)
@@ -331,8 +331,8 @@ def dispatch_mixed_grid(
     returns — the double-buffered shard loop dispatches shard ``k+1``
     before finalizing shard ``k``.
     """
-    from jax.experimental import enable_x64
 
+    import jax
     from repro.autotune import jaxgrid
 
     if dtype not in _DTYPES:
@@ -344,7 +344,7 @@ def dispatch_mixed_grid(
         "sweepdevice/dispatch", "sweepdevice",
         dtype=dtype, n_scenarios=len(sb), n_machines=len(machines),
     ):
-        with enable_x64():
+        with jax.enable_x64(True):
             # Machine arrays MUST pack inside the x64 scope: outside it
             # the int64 leaves silently truncate to int32.
             mp = jaxgrid.machine_arrays(
@@ -694,8 +694,8 @@ def sweep_device_stats(
     twins, so a gate trained from it matches host-reduced training up to
     bin-edge ulps regardless of the evaluation ``dtype``.
     """
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.autotune import jaxgrid
     from repro.learn.stats import GateStats, _hist_shape
@@ -732,7 +732,7 @@ def sweep_device_stats(
             bc_acc[key] = np.zeros(L, dtype=np.int64)
         return key
 
-    with enable_x64():
+    with jax.enable_x64(True):
         mp_dt = jaxgrid.machine_arrays(
             machines, dtype=None if dtype == "float64" else dtype
         )
@@ -890,7 +890,6 @@ def device_merge_stats(stats_list):
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.learn.stats import GateStats
 
@@ -906,7 +905,7 @@ def device_merge_stats(stats_list):
             )
         if other.hist.shape != first.hist.shape:
             raise ValueError("GateStats bin layouts differ")
-    with enable_x64():
+    with jax.enable_x64(True):
         stacked = jnp.asarray(
             np.stack([s.hist for s in stats_list]), dtype=jnp.int64
         )

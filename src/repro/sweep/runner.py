@@ -278,7 +278,6 @@ def _device_sharded_grid(
     :class:`GridResult` is assembled.
     """
     import jax
-    from jax.experimental import enable_x64
 
     from repro.autotune import jaxgrid
 
@@ -299,7 +298,7 @@ def _device_sharded_grid(
             a = np.concatenate([a, tail])
         return np.ascontiguousarray(a.reshape((D, size) + a.shape[1:]))
 
-    with enable_x64():
+    with jax.enable_x64(True):
         mp = jaxgrid.machine_arrays(machines)
         g_max = max(m.group for m in machines)
         # The machine arrays ride along as broadcast *operands*

@@ -53,14 +53,15 @@ def vmem_footprint(variant: KernelVariant, gemm: GemmShape, group: int) -> int:
     g = int(group)
     b = int(gemm.dtype_bytes)
     c = variant.chunks
-    d = variant.buffer_depth
     if variant.kernel == "ficco_ag_matmul":
-        # Scratch mirrors the kernel: `depth` slots of (g, m_c, k) inbound
-        # chunks, the resident (k, n_local) weight shard, and `depth`
-        # slots of (g, m_c, n_local) outbound results.
-        m_c = max(1, (gemm.m // g) // c)
-        n_local = max(1, gemm.n // g)
-        return b * (d * g * m_c * gemm.k + gemm.k * n_local + d * g * m_c * n_local)
+        # The kernel's own plan and scratch, as allocated.
+        from repro.kernels.ficco_ag_matmul import fused_steps, fused_vmem_bytes
+
+        m_s = max(1, gemm.m // g)
+        steps, depth = fused_steps(m_s, g, variant)
+        return fused_vmem_bytes(
+            g, max(1, m_s // steps), gemm.k, max(1, gemm.n // g), depth, b
+        )
     if variant.kernel == "dma_exchange":
         # One gathered (g, m_c, k) exchange buffer per step kernel, plus
         # the blocked step-GEMM working set: double-buffered input
@@ -135,8 +136,14 @@ def check_variant(
 
     # -- fast-memory footprint -----------------------------------------
     vmem = vmem_footprint(variant, gemm, g)
-    if vmem > budget.vmem_bytes:
-        return f"vmem: footprint {vmem}B > budget {budget.vmem_bytes}B"
+    vmem_budget = budget.vmem_bytes
+    if variant.kernel == "ficco_ag_matmul":
+        # The fused kernel can use no more than the scoped VMEM it requests.
+        from repro.kernels.ficco_ag_matmul import VMEM_LIMIT_BYTES
+
+        vmem_budget = min(vmem_budget, VMEM_LIMIT_BYTES)
+    if vmem > vmem_budget:
+        return f"vmem: footprint {vmem}B > budget {vmem_budget}B"
 
     # -- semaphore slots -----------------------------------------------
     dma_s, reg_s = sem_slots(variant, g)

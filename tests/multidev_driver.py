@@ -22,7 +22,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro.compat import set_mesh, shard_map  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.core.schedule_types import Schedule  # noqa: E402
 from repro.overlap import (  # noqa: E402
     ficco_a2a_ffn,
@@ -47,8 +47,8 @@ def check(name: str, fn):
         traceback.print_exc()
 
 
-def make_mesh():
-    return jax.make_mesh((G,), (AXIS,))
+def tp_mesh():
+    return make_mesh((G,), (AXIS,))
 
 
 def tol(dtype):
@@ -59,7 +59,7 @@ def tol(dtype):
 
 def run_sharded(fn, mesh, x, w):
     wrapped = jax.jit(
-        shard_map(
+        jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(P(AXIS, None), P(None, AXIS)),
@@ -71,7 +71,7 @@ def run_sharded(fn, mesh, x, w):
 
 
 def schedules_allclose():
-    mesh = make_mesh()
+    mesh = tp_mesh()
     rng = np.random.default_rng(0)
     for m, n, k in [(128, 64, 64), (256, 128, 128), (512, 256, 64)]:
         for dtype in (jnp.float32, jnp.bfloat16):
@@ -100,7 +100,7 @@ def schedules_allclose():
 
 
 def ficco_linear_auto():
-    mesh = make_mesh()
+    mesh = tp_mesh()
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((256, 128)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((128, 128)), jnp.float32)
@@ -115,7 +115,7 @@ def ficco_linear_auto():
 
 def ficco_linear_indivisible_falls_back():
     """M/g not divisible by g again -> serial fallback, still correct."""
-    mesh = make_mesh()
+    mesh = tp_mesh()
     rng = np.random.default_rng(2)
     x = jnp.asarray(rng.standard_normal((8 * 9, 64)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((64, 64)), jnp.float32)
@@ -128,7 +128,7 @@ def ficco_linear_indivisible_falls_back():
 
 
 def moe_dispatch_equivalence():
-    mesh = make_mesh()
+    mesh = tp_mesh()
     rng = np.random.default_rng(3)
     e, c, d, f = 16, 32, 64, 128  # 16 experts over 8 devices
     e_local = e // G
@@ -142,7 +142,7 @@ def moe_dispatch_equivalence():
 
     def run(fn):
         wrapped = jax.jit(
-            shard_map(
+            jax.shard_map(
                 fn,
                 mesh=mesh,
                 in_specs=(P(AXIS, None, None), P(AXIS, None, None),
@@ -165,14 +165,14 @@ def moe_dispatch_equivalence():
 def hlo_uses_async_collectives():
     """The FiCCO schedules must lower to one chunk collective per step so
     XLA's scheduler can pipeline them (the DMA-offload story)."""
-    mesh = make_mesh()
+    mesh = tp_mesh()
     x = jnp.zeros((256, 128), jnp.float32)
     w = jnp.zeros((128, 128), jnp.float32)
     fn = functools.partial(
         run_schedule, Schedule.UNIFORM_FUSED_1D, axis_name=AXIS
     )
     wrapped = jax.jit(
-        shard_map(
+        jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(P(AXIS, None), P(None, AXIS)),
@@ -195,7 +195,7 @@ def ficco_in_model_matches_gspmd():
     from repro.models.model import build_model
     from repro.parallel.context import overlap_context
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = get_config("tinyllama-1.1b").reduced()
     cfg = dataclasses.replace(
         cfg, num_heads=4, num_kv_heads=4, d_ff=512, d_model=256
@@ -211,7 +211,7 @@ def ficco_in_model_matches_gspmd():
         logits, _ = model.forward(params, {"tokens": toks})
         return logits
 
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         base = np.asarray(jax.jit(fwd)(params, toks), np.float32)
         ov = OverlapConfig(mode="ficco_auto")
 
@@ -238,7 +238,7 @@ def shard_map_decode_attn_matches_reference():
     from repro.parallel import decode_attn
     from repro.models.layers import cache_attention
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(7)
     b, s, h, kv, d = 4, 4096, 8, 4, 32
     q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
@@ -248,7 +248,7 @@ def shard_map_decode_attn_matches_reference():
     v_c = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
     pos = jnp.int32(2500)
 
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         out, k2, v2 = jax.jit(decode_attn.shard_map_attn_decode)(
             q, k_new, v_new, k_c, v_c, pos
         )
@@ -271,9 +271,10 @@ def pallas_dma_backend_in_model():
     from repro.configs import get_config
     from repro.configs.base import OverlapConfig
     from repro.models.model import build_model
+    from repro.obs import metrics
     from repro.parallel.context import overlap_context
 
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     cfg = get_config("tinyllama-1.1b").reduced()
     cfg = dataclasses.replace(
         cfg, num_layers=1, num_heads=4, num_kv_heads=4, d_ff=512,
@@ -290,7 +291,7 @@ def pallas_dma_backend_in_model():
         logits, _ = model.forward(params, {"tokens": toks})
         return logits
 
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         base = np.asarray(jax.jit(fwd)(params, toks), np.float32)
         ov = OverlapConfig(mode="uniform-fused-1d", backend="pallas_dma")
 
@@ -301,6 +302,9 @@ def pallas_dma_backend_in_model():
 
         got = np.asarray(jax.jit(fwd_pallas)(params, toks), np.float32)
     np.testing.assert_allclose(got, base, rtol=2e-3, atol=2e-3)
+    reg = metrics.get_metrics()
+    assert reg.counter("tp/pallas_dma.dma").value > 0
+    assert reg.counter("tp/pallas_dma.xla").value == 0
 
 
 def main():

@@ -21,7 +21,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro.compat import shard_map  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.kernels import ref  # noqa: E402
 from repro.kernels.dma_exchange import (  # noqa: E402
     a2a_chunk_exchange,
@@ -32,6 +32,15 @@ from repro.overlap.moe import ficco_a2a_ffn, serial_a2a_ffn  # noqa: E402
 from repro.tune import default_variant  # noqa: E402
 
 G = 8
+# The fused kernel's shapes on 8 devices stay at 16 shard rows: with more,
+# the Mosaic interpreter stalls before the kernel body runs.  Devices that
+# entered the kernel block in semaphore waits inside their callbacks, and
+# the others never finish copying a VMEM scratch's initial value to the
+# host.  A kernel that only handshakes and remote-copies stalls the same
+# way on 8 devices once it allocates a 256 x 128 f32 VMEM scratch, and
+# completes with 16 rows, or on 4 devices with 1024.  The larger shapes
+# run on a 4-device sub-mesh.
+G_FUSED = 4
 AXIS = "tp"
 failures = []
 
@@ -46,8 +55,8 @@ def check(name, fn):
         traceback.print_exc()
 
 
-def mesh():
-    return jax.make_mesh((G,), (AXIS,))
+def mesh(g=G):
+    return make_mesh((g,), (AXIS,), devices=jax.devices()[:g])
 
 
 def exchange_matches_all_gather():
@@ -64,7 +73,7 @@ def exchange_matches_all_gather():
             return got, want
 
         got, want = jax.jit(
-            shard_map(
+            jax.shard_map(
                 body, mesh=m,
                 in_specs=P(AXIS, None),
                 out_specs=(P(AXIS, None, None), P(AXIS, None, None)),
@@ -89,7 +98,7 @@ def dma_schedule_matches_serial():
         return got, want
 
     got, want = jax.jit(
-        shard_map(
+        jax.shard_map(
             body, mesh=m,
             in_specs=(P(AXIS, None), P(None, AXIS)),
             out_specs=(P(None, AXIS), P(None, AXIS)),
@@ -102,14 +111,16 @@ def dma_schedule_matches_serial():
 
 
 def fused_kernel_matches_serial():
-    m = mesh()
     rng = np.random.default_rng(2)
-    for ms, k, n_local, dtype in [
-        (64, 128, 128, jnp.float32),
-        (32, 256, 128, jnp.bfloat16),
+    for g, ms, k, n_local, dtype in [
+        (G_FUSED, 64, 128, 128, jnp.float32),
+        (G_FUSED, 32, 256, 128, jnp.bfloat16),
+        (G, 16, 128, 128, jnp.float32),
+        (G, 16, 128, 128, jnp.bfloat16),
     ]:
-        x = jnp.asarray(rng.standard_normal((G * ms, k)), dtype)
-        w = jnp.asarray(rng.standard_normal((k, G * n_local)), dtype)
+        m = mesh(g)
+        x = jnp.asarray(rng.standard_normal((g * ms, k)), dtype)
+        w = jnp.asarray(rng.standard_normal((k, g * n_local)), dtype)
 
         def body(xs, ws):
             got = ficco_ag_matmul_fused(
@@ -119,7 +130,7 @@ def fused_kernel_matches_serial():
             return got, want
 
         got, want = jax.jit(
-            shard_map(
+            jax.shard_map(
                 body, mesh=m,
                 in_specs=(P(AXIS, None), P(None, AXIS)),
                 out_specs=(P(None, AXIS), P(None, AXIS)),
@@ -140,17 +151,22 @@ def ag_fused_variants_bit_identical():
     """Chunk-count / buffer-depth / dispatch-order variants of the fused
     AG kernel must be BIT-identical to the default: every output row is
     one full-K dot whichever slot/step order produced its operand."""
-    m = mesh()
+    for g, ms in ((G_FUSED, 64), (G, 16)):
+        _ag_fused_variants_bit_identical(g, ms)
+
+
+def _ag_fused_variants_bit_identical(g, ms):
+    m = mesh(g)
     rng = np.random.default_rng(3)
-    ms, k, n_local = 64, 128, 128
-    x = jnp.asarray(rng.standard_normal((G * ms, k)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((k, G * n_local)), jnp.float32)
-    base = default_variant("ficco_ag_matmul", group=G)
+    k, n_local = 128, 128
+    x = jnp.asarray(rng.standard_normal((g * ms, k)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((k, g * n_local)), jnp.float32)
+    base = default_variant("ficco_ag_matmul", group=g)
     variants = [
         base,
-        dataclasses.replace(base, chunks=4),
+        dataclasses.replace(base, chunks=2 * g),
         dataclasses.replace(base, buffer_depth=3),
-        dataclasses.replace(base, chunks=4, buffer_depth=3),
+        dataclasses.replace(base, chunks=2 * g, buffer_depth=3),
         dataclasses.replace(base, dispatch_order="reverse"),
     ]
 
@@ -162,7 +178,7 @@ def ag_fused_variants_bit_identical():
 
         return np.asarray(
             jax.jit(
-                shard_map(
+                jax.shard_map(
                     body, mesh=m,
                     in_specs=(P(AXIS, None), P(None, AXIS)),
                     out_specs=P(None, AXIS),
@@ -195,7 +211,7 @@ def dma_schedule_variants_match():
 
         return np.asarray(
             jax.jit(
-                shard_map(
+                jax.shard_map(
                     body, mesh=m,
                     in_specs=(P(AXIS, None), P(None, AXIS)),
                     out_specs=(P(None, AXIS)),
@@ -238,7 +254,7 @@ def a2a_ffn_variants_bit_identical():
 
         return np.asarray(
             jax.jit(
-                shard_map(
+                jax.shard_map(
                     body, mesh=m,
                     in_specs=(P(AXIS, None, None), P(AXIS, None, None),
                               P(AXIS, None, None)),
@@ -262,7 +278,7 @@ def a2a_ffn_variants_bit_identical():
 
     serial = np.asarray(
         jax.jit(
-            shard_map(
+            jax.shard_map(
                 serial_body, mesh=m,
                 in_specs=(P(AXIS, None, None), P(AXIS, None, None),
                           P(AXIS, None, None)),

@@ -136,11 +136,10 @@ class TestDifferentiability:
         """d E[heuristic-picked time] / d tau exists and is informative."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         from repro.autotune import expected_heuristic_time
 
-        with enable_x64():
+        with jax.enable_x64(True):
             f = lambda t: expected_heuristic_time(t, TABLE_I, MI300X)
             g = jax.grad(f)(jnp.asarray(0.02, jnp.float64))
         assert np.isfinite(float(g))
@@ -151,11 +150,10 @@ class TestDifferentiability:
         faster HBM strictly reduces mean schedule time."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         from repro.autotune import evaluate_grid_raw, machine_arrays
 
-        with enable_x64():
+        with jax.enable_x64(True):
             mp = machine_arrays((MI300X,))
 
             def mean_total(link_bw):
@@ -410,6 +408,43 @@ class TestTunerAndCache:
         grid = np_evaluate_grid([GemmShape(65536, 8192, 8192)], (MI300X,))
         assert s is GRID_SCHEDULES[int(grid.best_idx()[0, 0])]
 
+    def test_resolve_schedule_counts_tuner_fallback(self, monkeypatch):
+        """A shape the tuner's model cannot rank resolves through the
+        static tree, counted as ``autotune_fallback``."""
+        from repro.autotune import Autotuner, get_tuner
+        from repro.core import select_schedule
+        from repro.obs import metrics
+        from repro.overlap.api import resolve_schedule
+
+        def unrankable(self, *a, **kw):
+            raise ValueError("no valid schedule")
+
+        monkeypatch.setattr(Autotuner, "executable_ranking", unrankable)
+        fallback = metrics.get_metrics().counter(
+            "overlap/resolve.autotune_fallback"
+        )
+        before = fallback.value
+        gemm = GemmShape(65536, 8192, 8192)
+        s = resolve_schedule(
+            "autotune", m=gemm.m, n=gemm.n, k=gemm.k, machine=MI300X,
+            group=MI300X.group,
+        )
+        assert fallback.value == before + 1
+        assert get_tuner().pick(gemm, MI300X).source == "heuristic"
+        assert s is select_schedule(gemm, MI300X).schedule
+
+    def test_resolve_schedule_autotune_raises_tuner_errors(self, monkeypatch):
+        """Errors other than the tuner's own "no answer" propagate."""
+        import repro.autotune
+        from repro.overlap.api import resolve_schedule
+
+        def broken():
+            raise ImportError("tuner unavailable")
+
+        monkeypatch.setattr(repro.autotune, "get_tuner", broken)
+        with pytest.raises(ImportError):
+            resolve_schedule("autotune", m=65536, n=8192, k=8192, group=8)
+
 
 class TestCacheSchemaV2:
     """Schema v2: the ragged step-profile digest joined the key schema
@@ -556,7 +591,6 @@ import numpy as np
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.overlap import ficco_linear
 from repro.autotune import get_tuner
 
@@ -565,7 +599,7 @@ rng = np.random.default_rng(0)
 x = jnp.asarray(rng.standard_normal((512, 256)), jnp.float32)
 w = jnp.asarray(rng.standard_normal((256, 256)), jnp.float32)
 fn = jax.jit(
-    shard_map(
+    jax.shard_map(
         functools.partial(ficco_linear, axis_name="tp", schedule="autotune"),
         mesh=mesh,
         in_specs=(P("tp", None), P(None, "tp")),

@@ -372,10 +372,10 @@ class TestClosedFormPipeline:
         from repro.sweep import host_batch
 
         sb = host_batch(2048, seed=13)
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
-        with enable_x64():
+        with jax.enable_x64(True):
             mp = jaxgrid.machine_arrays(MACHINES)
             g_max = max(m.group for m in MACHINES)
             scan = jaxgrid.evaluate_grid_raw(sb, mp, g_max=g_max)
@@ -398,15 +398,15 @@ class TestClosedFormPipeline:
         )
 
     def test_floor_div_exact(self):
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         from repro.autotune.jaxgrid import _floor_div
 
         rng = np.random.default_rng(0)
         a = rng.integers(0, 1 << 26, size=4096).astype(np.int64)
         b = rng.integers(1, 1 << 20, size=4096).astype(np.int64)
-        with enable_x64():
+        with jax.enable_x64(True):
             got = np.asarray(_floor_div(jnp.asarray(a), jnp.asarray(b)))
             assert np.array_equal(got, a // b)
             # The negated-ceil pattern: -_floor_div(-a, b) == ceil(a/b).

@@ -97,6 +97,15 @@ def test_accumulate_fallback_for_odd_shapes():
     )
 
 
+def test_ops_refuse_backends_without_pallas_tpu(monkeypatch):
+    """Only a TPU compiles the kernels and only the CPU interprets them;
+    any other backend is refused instead of silently interpreted."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    x = jnp.ones((128, 128), jnp.float32)
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.matmul(x, x)
+
+
 def test_ops_wrappers_interpret_on_cpu():
     assert jax.default_backend() == "cpu"
     rng = np.random.default_rng(5)

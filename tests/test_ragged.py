@@ -144,8 +144,8 @@ class TestMaskedPipelinePrimitive:
 
     @pytest.mark.parametrize("seed", [0, 1, 2, 3])
     def test_three_way_random(self, seed):
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         from repro.autotune.jaxgrid import pipeline_jax
 
@@ -164,7 +164,7 @@ class TestMaskedPipelinePrimitive:
                 got_np = core_batch.pipeline_vec(
                     comm, compute, deps, c_act, w_act
                 )
-                with enable_x64():
+                with jax.enable_x64(True):
                     got_jx = pipeline_jax(
                         [jnp.asarray(c) for c in comm],
                         [jnp.asarray(w) for w in compute],
@@ -438,7 +438,6 @@ class TestSkewAwareMoeKernel:
         from jax.sharding import Mesh
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
         from repro.overlap.moe import ficco_a2a_ffn, serial_a2a_ffn
 
         mesh = Mesh(np.array(jax.devices()[:1]), ("ep",))
@@ -450,7 +449,7 @@ class TestSkewAwareMoeKernel:
         profile = StepProfile.from_weights([6, 3, 2, 1])
 
         def run(fn, **kw):
-            wrapped = shard_map(
+            wrapped = jax.shard_map(
                 lambda a, b, c_: fn(a, b, c_, axis_name="ep", **kw),
                 mesh=mesh,
                 in_specs=(P(), P(), P()),
@@ -472,7 +471,6 @@ class TestSkewAwareMoeKernel:
         from jax.sharding import Mesh
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
         from repro.overlap.moe import ficco_a2a_ffn
 
         mesh = Mesh(np.array(jax.devices()[:1]), ("ep",))
@@ -480,7 +478,7 @@ class TestSkewAwareMoeKernel:
         w_up = jnp.zeros((2, 4, 8), jnp.float32)
         w_dn = jnp.zeros((2, 8, 4), jnp.float32)
         with pytest.raises(ValueError):
-            shard_map(
+            jax.shard_map(
                 lambda a, b, c_: ficco_a2a_ffn(
                     a, b, c_, axis_name="ep", chunk_sizes=(3, 3)
                 ),
