@@ -185,27 +185,30 @@ def ficco_uniform_fused_1d_dma(
     if reverse:
         order.reverse()
     for s in order:
-        gathered = a2a_chunk_exchange(
-            chunks[s],
-            axis_name=axis_name,
-            group=g,
-            interpret=interpret,
-            reverse=reverse,
-        )
-        flat = gathered.reshape(rows, k)
-        if blocked:
-            from repro.kernels.chunked_gemm import chunked_matmul
-
-            step_out = chunked_matmul(
-                flat,
-                w,
-                block_m=variant.block_m,
-                block_n=variant.block_n,
-                block_k=k,
+        # The scope names the kernel in the compiled program's metadata.
+        with jax.named_scope("ficco/exchange"):
+            gathered = a2a_chunk_exchange(
+                chunks[s],
+                axis_name=axis_name,
+                group=g,
                 interpret=interpret,
-            ).reshape(g, m_c, n_local)
-        else:
-            step_out = (flat @ w).reshape(g, m_c, n_local)
+                reverse=reverse,
+            )
+        with jax.named_scope("ficco/gemm"):
+            flat = gathered.reshape(rows, k)
+            if blocked:
+                from repro.kernels.chunked_gemm import chunked_matmul
+
+                step_out = chunked_matmul(
+                    flat,
+                    w,
+                    block_m=variant.block_m,
+                    block_n=variant.block_n,
+                    block_k=k,
+                    interpret=interpret,
+                ).reshape(g, m_c, n_local)
+            else:
+                step_out = (flat @ w).reshape(g, m_c, n_local)
         for d in range(g):
             out = lax.dynamic_update_slice(
                 out, step_out[d].astype(out.dtype), (d * m_s + s * m_c, 0)

@@ -46,18 +46,21 @@ def norm_init(d: int, kind: str, dtype):
 
 
 def apply_norm(params, x: jax.Array, kind: str) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    if kind == "rmsnorm":
-        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + 1e-6)
-        return (y * params["scale"].astype(jnp.float32)).astype(x.dtype)
-    mu = jnp.mean(xf, -1, keepdims=True)
-    var = jnp.mean((xf - mu) ** 2, -1, keepdims=True)
-    y = (xf - mu) * jax.lax.rsqrt(var + 1e-6)
-    if kind == "layernorm":
-        y = y * params["scale"].astype(jnp.float32) + params["bias"].astype(
-            jnp.float32
-        )
-    return y.astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        if kind == "rmsnorm":
+            y = xf * jax.lax.rsqrt(
+                jnp.mean(xf * xf, -1, keepdims=True) + 1e-6
+            )
+            return (y * params["scale"].astype(jnp.float32)).astype(x.dtype)
+        mu = jnp.mean(xf, -1, keepdims=True)
+        var = jnp.mean((xf - mu) ** 2, -1, keepdims=True)
+        y = (xf - mu) * jax.lax.rsqrt(var + 1e-6)
+        if kind == "layernorm":
+            y = y * params["scale"].astype(jnp.float32) + params[
+                "bias"
+            ].astype(jnp.float32)
+        return y.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -270,21 +273,26 @@ def attn_apply(
     b, s, _ = x.shape
     h, kv, hd = dims.num_heads, dims.num_kv_heads, dims.head_dim
     src = kv_for_cross if kv_for_cross is not None else x
-    q = (x @ params["wq"]).reshape(b, s, h, hd)
-    k = (src @ params["wk"]).reshape(b, src.shape[1], kv, hd)
-    v = (src @ params["wv"]).reshape(b, src.shape[1], kv, hd)
-    q = constrain(q, BATCH_AXES, None, MODEL_AXIS, None)
-    k = constrain(k, BATCH_AXES, None, MODEL_AXIS if kv > 1 else None, None)
-    v = constrain(v, BATCH_AXES, None, MODEL_AXIS if kv > 1 else None, None)
-    if kv_for_cross is None:
-        q = apply_rope(q, positions, rope_theta)
-        k = apply_rope(k, positions, rope_theta)
-        out = blockwise_attention(q, k, v, causal=True, window=window)
-    else:
-        out = blockwise_attention(q, k, v, causal=False)
-    out = out.reshape(b, s, h * hd)
-    y = out @ params["wo"]
-    return constrain(y, BATCH_AXES, None, None)
+    kv_axis = MODEL_AXIS if kv > 1 else None
+    with jax.named_scope("attn"):
+        with jax.named_scope("qkv"):
+            q = (x @ params["wq"]).reshape(b, s, h, hd)
+            k = (src @ params["wk"]).reshape(b, src.shape[1], kv, hd)
+            v = (src @ params["wv"]).reshape(b, src.shape[1], kv, hd)
+            q = constrain(q, BATCH_AXES, None, MODEL_AXIS, None)
+            k = constrain(k, BATCH_AXES, None, kv_axis, None)
+            v = constrain(v, BATCH_AXES, None, kv_axis, None)
+            if kv_for_cross is None:
+                q = apply_rope(q, positions, rope_theta)
+                k = apply_rope(k, positions, rope_theta)
+        with jax.named_scope("core"):
+            if kv_for_cross is None:
+                out = blockwise_attention(q, k, v, causal=True, window=window)
+            else:
+                out = blockwise_attention(q, k, v, causal=False)
+        with jax.named_scope("o"):
+            y = out.reshape(b, s, h * hd) @ params["wo"]
+            return constrain(y, BATCH_AXES, None, None)
 
 
 def attn_decode(
@@ -298,41 +306,52 @@ def attn_decode(
     window: Optional[int] = None,
 ):
     """One-token decode. x: (B, 1, d); cache: {"k","v"} (B, S, KV, D)."""
-    b, one, _ = x.shape
-    h, kv, hd = dims.num_heads, dims.num_kv_heads, dims.head_dim
-    q = (x @ params["wq"]).reshape(b, 1, h, hd)
-    k = (x @ params["wk"]).reshape(b, 1, kv, hd)
-    v = (x @ params["wv"]).reshape(b, 1, kv, hd)
-    posv = jnp.full((b, 1), pos)
-    q = apply_rope(q, posv, rope_theta)
-    k = apply_rope(k, posv, rope_theta)
-
     from repro.parallel.context import get_overlap
 
-    ov = get_overlap()
-    if ov is not None and getattr(ov, "decode_attn", "gspmd") == "shard_map":
-        from repro.parallel import decode_attn
+    b, one, _ = x.shape
+    h, kv, hd = dims.num_heads, dims.num_kv_heads, dims.head_dim
+    with jax.named_scope("attn"):
+        with jax.named_scope("qkv"):
+            q = (x @ params["wq"]).reshape(b, 1, h, hd)
+            k = (x @ params["wk"]).reshape(b, 1, kv, hd)
+            v = (x @ params["wv"]).reshape(b, 1, kv, hd)
+            posv = jnp.full((b, 1), pos)
+            q = apply_rope(q, posv, rope_theta)
+            k = apply_rope(k, posv, rope_theta)
 
-        if decode_attn.applicable(cache["k"], window):
-            out, k_cache, v_cache = decode_attn.shard_map_attn_decode(
-                q, k, v, cache["k"], cache["v"], pos
+        ov = get_overlap()
+        if (ov is not None
+                and getattr(ov, "decode_attn", "gspmd") == "shard_map"):
+            from repro.parallel import decode_attn
+
+            if decode_attn.applicable(cache["k"], window):
+                with jax.named_scope("core"):
+                    out, k_cache, v_cache = (
+                        decode_attn.shard_map_attn_decode(
+                            q, k, v, cache["k"], cache["v"], pos
+                        )
+                    )
+                with jax.named_scope("o"):
+                    y = out.reshape(b, 1, h * hd) @ params["wo"]
+                return y, {"k": k_cache, "v": v_cache}
+
+        with jax.named_scope("kv_cache"):
+            s_cache = cache["k"].shape[1]
+            slot = pos % s_cache if window is not None else pos
+            k_cache = lax.dynamic_update_slice(
+                cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0)
             )
+            v_cache = lax.dynamic_update_slice(
+                cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0)
+            )
+        with jax.named_scope("core"):
+            out = cache_attention(
+                q, k_cache, v_cache, valid_len=pos + 1,
+                ring=window is not None,
+            )
+        with jax.named_scope("o"):
             y = out.reshape(b, 1, h * hd) @ params["wo"]
-            return y, {"k": k_cache, "v": v_cache}
-
-    s_cache = cache["k"].shape[1]
-    slot = pos % s_cache if window is not None else pos
-    k_cache = lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0)
-    )
-    v_cache = lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0)
-    )
-    out = cache_attention(
-        q, k_cache, v_cache, valid_len=pos + 1, ring=window is not None
-    )
-    y = out.reshape(b, 1, h * hd) @ params["wo"]
-    return y, {"k": k_cache, "v": v_cache}
+        return y, {"k": k_cache, "v": v_cache}
 
 
 # ---------------------------------------------------------------------------
@@ -366,25 +385,26 @@ def mlp_apply(params, x: jax.Array) -> jax.Array:
     from repro.parallel.context import get_overlap
 
     ov = get_overlap()
-    if ov is not None and ov.mode != "gspmd_serial":
-        from repro.parallel import tp
+    with jax.named_scope("mlp"):
+        with jax.named_scope("up_gate"):
+            h = None
+            if ov is not None and ov.mode != "gspmd_serial":
+                from repro.parallel import tp
 
-        if tp.overlap_applicable(x, params["w_up"]):
-            h = tp.tp_ficco_linear(x, params["w_up"], ov)
-            if "w_gate" in params:
-                g = tp.tp_ficco_linear(x, params["w_gate"], ov)
-                h = jax.nn.silu(g) * h
-            else:
-                h = jax.nn.gelu(h)
+                if tp.overlap_applicable(x, params["w_up"]):
+                    h = tp.tp_ficco_linear(x, params["w_up"], ov)
+                    if "w_gate" in params:
+                        g = tp.tp_ficco_linear(x, params["w_gate"], ov)
+                        h = jax.nn.silu(g) * h
+                    else:
+                        h = jax.nn.gelu(h)
+            if h is None:
+                h = x @ params["w_up"]
+                if "w_gate" in params:
+                    h = jax.nn.silu(x @ params["w_gate"]) * h
+                else:
+                    h = jax.nn.gelu(h)
             h = constrain(h, BATCH_AXES, None, MODEL_AXIS)
+        with jax.named_scope("down"):
             y = h @ params["w_down"]
             return constrain(y, BATCH_AXES, None, None)
-
-    h = x @ params["w_up"]
-    if "w_gate" in params:
-        h = jax.nn.silu(x @ params["w_gate"]) * h
-    else:
-        h = jax.nn.gelu(h)
-    h = constrain(h, BATCH_AXES, None, MODEL_AXIS)
-    y = h @ params["w_down"]
-    return constrain(y, BATCH_AXES, None, None)

@@ -411,20 +411,21 @@ class Model:
             )
             period_fn = jax.checkpoint(period_fn, policy=policy)
 
-        if cfg.scan_layers and self.n_periods > 1:
-            def body(x, pp):
-                x, aux = period_fn(x, pp)
-                return x, aux
+        with jax.named_scope("layers"):
+            if cfg.scan_layers and self.n_periods > 1:
+                def body(x, pp):
+                    x, aux = period_fn(x, pp)
+                    return x, aux
 
-            x, auxs = lax.scan(body, x, stack_params)
-            return x, jnp.sum(auxs)
-        # unrolled
-        aux = jnp.zeros((), jnp.float32)
-        for i in range(self.n_periods):
-            pp = jax.tree.map(lambda a, i=i: a[i], stack_params)
-            x, a = period_fn(x, pp)
-            aux = aux + a
-        return x, aux
+                x, auxs = lax.scan(body, x, stack_params)
+                return x, jnp.sum(auxs)
+            # unrolled
+            aux = jnp.zeros((), jnp.float32)
+            for i in range(self.n_periods):
+                pp = jax.tree.map(lambda a, i=i: a[i], stack_params)
+                x, a = period_fn(x, pp)
+                aux = aux + a
+            return x, aux
 
     def _encode(self, params, enc_frames):
         cfg = self.config
@@ -454,8 +455,9 @@ class Model:
         enc_frames (B, S_enc, d).  Returns (logits, aux_loss)."""
         cfg = self.config
         tokens = batch["tokens"]
-        x = params["embed"][tokens].astype(_dtype(cfg))
-        x = constrain(x, BATCH_AXES, MODEL_AXIS, None)
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens].astype(_dtype(cfg))
+            x = constrain(x, BATCH_AXES, MODEL_AXIS, None)
 
         enc_out = None
         if self.is_encdec:
@@ -478,13 +480,14 @@ class Model:
         return logits, aux
 
     def _unembed(self, params, x):
-        w = (
-            params["embed"].T
-            if self.config.tie_embeddings
-            else params["unembed"]
-        )
-        logits = x @ w.astype(x.dtype)
-        return constrain(logits, BATCH_AXES, None, MODEL_AXIS)
+        with jax.named_scope("logits"):
+            w = (
+                params["embed"].T
+                if self.config.tie_embeddings
+                else params["unembed"]
+            )
+            logits = x @ w.astype(x.dtype)
+            return constrain(logits, BATCH_AXES, None, MODEL_AXIS)
 
     def loss(self, params, batch: dict):
         """Vocab-parallel-safe cross entropy: all reductions run over the
@@ -558,7 +561,8 @@ class Model:
     def decode_step(self, params, cache, tokens, pos):
         """tokens: (B, 1) int32; pos: scalar position. -> (logits, cache)."""
         cfg = self.config
-        x = params["embed"][tokens].astype(_dtype(cfg))
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens].astype(_dtype(cfg))
 
         def body(x, args):
             pp, pc = args
@@ -571,18 +575,19 @@ class Model:
                 new_pc.append(c2)
             return x, new_pc
 
-        if cfg.scan_layers and self.n_periods > 1:
-            x, new_cache = lax.scan(body, x, (params["layers"], cache))
-        else:
-            new_caches = []
-            for i in range(self.n_periods):
-                pp = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-                pc = jax.tree.map(lambda a, i=i: a[i], cache)
-                x, npc = body(x, (pp, pc))
-                new_caches.append(npc)
-            new_cache = jax.tree.map(
-                lambda *xs: jnp.stack(xs), *new_caches
-            )
+        with jax.named_scope("layers"):
+            if cfg.scan_layers and self.n_periods > 1:
+                x, new_cache = lax.scan(body, x, (params["layers"], cache))
+            else:
+                new_caches = []
+                for i in range(self.n_periods):
+                    pp = jax.tree.map(lambda a, i=i: a[i], params["layers"])
+                    pc = jax.tree.map(lambda a, i=i: a[i], cache)
+                    x, npc = body(x, (pp, pc))
+                    new_caches.append(npc)
+                new_cache = jax.tree.map(
+                    lambda *xs: jnp.stack(xs), *new_caches
+                )
         x = layers.apply_norm(params["final_norm"], x, cfg.norm)
         logits = self._unembed(params, x)
         return logits, new_cache

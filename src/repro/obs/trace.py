@@ -26,6 +26,19 @@ and registers an ``atexit`` export, so any launcher/script becomes
 traceable without a code change::
 
     REPRO_TRACE=sweep.trace.json python scripts/sweep.py ...
+
+The profiler sink puts the same spans on the device trace's clock:
+with :func:`enable_profiler` on, every span also opens a
+``jax.profiler.TraceAnnotation`` (a host TraceMe) of the same name and
+args, which a running ``jax.profiler`` session records beside the
+device's ops.  It is independent of the JSON sink; JAX is imported only
+when it is switched on::
+
+    trace.enable_profiler()
+    jax.profiler.start_trace(logdir)
+    ... instrumented work ...
+    jax.profiler.stop_trace()
+    trace.disable_profiler()
 """
 
 from __future__ import annotations
@@ -60,7 +73,8 @@ NULL_SPAN = _NullSpan()
 class _Span:
     """One open duration ("X") event; closes on ``__exit__``."""
 
-    __slots__ = ("_tracer", "name", "cat", "pid", "tid", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "pid", "tid", "args", "_t0",
+                 "_me")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  pid: int, tid: int, args: dict):
@@ -71,17 +85,29 @@ class _Span:
         self.tid = tid
         self.args = args
         self._t0 = 0.0
+        self._me = None  # the profiler sink's TraceMe while open
 
     def set(self, **args) -> None:
         """Attach/overwrite args (e.g. the decision once it's known)."""
         self.args.update(args)
+        if self._me is not None:
+            self._me.set_metadata(**args)
 
     def __enter__(self) -> "_Span":
+        annotate = self._tracer.annotate
+        if annotate is not None:
+            self._me = annotate(self.name, **self.args)
+            self._me.__enter__()
         self._t0 = self._tracer._now_us()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = self._tracer._now_us()
+        if self._me is not None:
+            self._me.__exit__(*exc)
+            self._me = None
+        if not self._tracer.record:
+            return False
         self._tracer._append({
             "name": self.name,
             "cat": self.cat,
@@ -102,10 +128,16 @@ class Tracer:
     (``perf_counter`` based — monotonic, sub-microsecond resolution).
     Appends are a single list.append under the GIL, so spans opened from
     side threads (e.g. a background re-fit thread) interleave safely.
+
+    Two sinks: ``record`` keeps events for the JSON export; ``annotate``,
+    when set (``jax.profiler.TraceAnnotation``), also opens each span as
+    a host TraceMe in the JAX profiler's trace (:func:`enable_profiler`).
     """
 
-    def __init__(self, path: str | None = None):
+    def __init__(self, path: str | None = None, *, record: bool = True):
         self.path = path
+        self.record = record
+        self.annotate = None
         self.events: list[dict] = []
         self._lock = threading.Lock()
         self._named: set[tuple] = set()
@@ -120,7 +152,8 @@ class Tracer:
         return (time.perf_counter() - self._t0) * 1e6
 
     def _append(self, event: dict) -> None:
-        self.events.append(event)  # atomic under the GIL
+        if self.record:
+            self.events.append(event)  # atomic under the GIL
 
     # -- event emitters -------------------------------------------------
 
@@ -201,30 +234,73 @@ _TRACER: Tracer | None = None
 
 
 def enable(path: str | None = None) -> Tracer:
-    """Install a process-wide tracer (``path`` is the default export)."""
+    """Install a process-wide tracer (``path`` is the default export).
+
+    A profiler sink that is on stays on."""
     global _TRACER
-    _TRACER = Tracer(path)
+    prev, _TRACER = _TRACER, Tracer(path)
+    if prev is not None:
+        _TRACER.annotate = prev.annotate
     return _TRACER
 
 
 def disable() -> str | None:
-    """Uninstall the tracer; exports first if it has a path.
+    """Uninstall the tracer's JSON sink; exports first if it has a path.
+    A profiler sink that is on stays on.
 
     Returns the exported path (None if nothing was exported).
     """
     global _TRACER
-    t, _TRACER = _TRACER, None
-    if t is not None and t.path:
-        return t.export()
-    return None
+    t = _TRACER
+    if t is None or not t.record:
+        return None
+    _TRACER = None
+    if t.annotate is not None:
+        _TRACER = Tracer(record=False)
+        _TRACER.annotate = t.annotate
+    return t.export() if t.path else None
+
+
+def enable_profiler() -> Tracer:
+    """Switch the profiler sink on: every span from now on also opens a
+    ``jax.profiler.TraceAnnotation`` with its name and args, so that a
+    ``jax.profiler`` trace holds the program's spans on the device's
+    clock.  Installs a tracer that records nothing else if none is."""
+    global _TRACER
+    from jax.profiler import TraceAnnotation  # lazy: the package is stdlib
+
+    if _TRACER is None:
+        _TRACER = Tracer(record=False)
+    _TRACER.annotate = TraceAnnotation
+    return _TRACER
+
+
+def disable_profiler() -> None:
+    """Switch the profiler sink off; the JSON sink, if on, stays on."""
+    global _TRACER
+    t = _TRACER
+    if t is None:
+        return
+    t.annotate = None
+    if not t.record:
+        _TRACER = None
+
+
+def profiler_enabled() -> bool:
+    t = _TRACER
+    return t is not None and t.annotate is not None
 
 
 def enabled() -> bool:
-    return _TRACER is not None
+    """Whether the JSON sink is on."""
+    t = _TRACER
+    return t is not None and t.record
 
 
 def get_tracer() -> Tracer | None:
-    return _TRACER
+    """The tracer whose JSON sink is on (None if it is off)."""
+    t = _TRACER
+    return t if t is not None and t.record else None
 
 
 def span(name: str, cat: str = "repro", *,
@@ -413,6 +489,9 @@ __all__ = [
     "disable",
     "enabled",
     "get_tracer",
+    "enable_profiler",
+    "disable_profiler",
+    "profiler_enabled",
     "span",
     "instant",
     "counter",

@@ -48,14 +48,26 @@ def _my_index(axis_name: str):
     return lax.axis_index(axis_name)
 
 
+# Stable scopes in the compiled program's metadata, so that a profile
+# attributes each device op to a schedule's communication or its GEMMs.
+def _exchange():
+    return jax.named_scope("ficco/exchange")
+
+
+def _gemm():
+    return jax.named_scope("ficco/gemm")
+
+
 # ---------------------------------------------------------------------------
 # Baselines
 # ---------------------------------------------------------------------------
 
 def serial_ag_matmul(x: jax.Array, w: jax.Array, *, axis_name: str) -> jax.Array:
     """Paper Fig. 3(b): all-gather the input shards, then one big GEMM."""
-    x_full = lax.all_gather(x, axis_name, axis=0, tiled=True)  # (M, K)
-    return x_full @ w
+    with _exchange():
+        x_full = lax.all_gather(x, axis_name, axis=0, tiled=True)  # (M, K)
+    with _gemm():
+        return x_full @ w
 
 
 def shard_p2p_matmul(
@@ -77,11 +89,12 @@ def shard_p2p_matmul(
     buf = x
     for step in range(g):
         src = (me - step) % g  # whose shard we currently hold
-        out = lax.dynamic_update_slice(
-            out, (buf @ w).astype(out.dtype), (src * m_s, 0)
-        )
+        with _gemm():
+            piece = (buf @ w).astype(out.dtype)
+        out = lax.dynamic_update_slice(out, piece, (src * m_s, 0))
         if step != g - 1:
-            buf = lax.ppermute(buf, axis_name, perm)
+            with _exchange():
+                buf = lax.ppermute(buf, axis_name, perm)
     return out
 
 
@@ -112,9 +125,11 @@ def ficco_uniform_fused_1d(
     for s in range(g):
         # One chunk to every peer, one chunk from every peer: the paper's
         # simultaneous all-to-all step (all links busy on a direct topology).
-        gathered = lax.all_gather(chunks[s], axis_name, axis=0)  # (g, m_c, K)
-        step_buf = gathered.reshape(g * m_c, k)  # Gather
-        step_out = step_buf @ w  # identical GEMM every step
+        with _exchange():
+            gathered = lax.all_gather(chunks[s], axis_name, axis=0)
+        step_buf = gathered.reshape(g * m_c, k)  # Gather (g, m_c, K)
+        with _gemm():
+            step_out = step_buf @ w  # identical GEMM every step
         # Scatter: row block from device d lands at global row d*m_s + s*m_c.
         step_out = step_out.reshape(g, m_c, n_local)
         for d in range(g):
@@ -140,17 +155,19 @@ def ficco_hetero_fused_1d(
     out = jnp.zeros((g * m_s, n_local), dtype=jnp.result_type(x, w))
 
     # Step 0: local shard, no communication dependency.
-    out = lax.dynamic_update_slice(
-        out, (x @ w).astype(out.dtype), (me * m_s, 0)
-    )
+    with _gemm():
+        local = (x @ w).astype(out.dtype)
+    out = lax.dynamic_update_slice(out, local, (me * m_s, 0))
 
     chunks = _chunk_rows(x, g)
     for s in range(g):
-        gathered = lax.all_gather(chunks[s], axis_name, axis=0)  # (g, m_c, K)
+        with _exchange():
+            gathered = lax.all_gather(chunks[s], axis_name, axis=0)
         # Remote-only gather: rotate so our own chunk is last, drop it.
         rolled = jnp.roll(gathered, -(me + 1), axis=0)[: g - 1]
         step_buf = rolled.reshape((g - 1) * m_c, k)
-        step_out = (step_buf @ w).reshape(g - 1, m_c, n_local)
+        with _gemm():
+            step_out = (step_buf @ w).reshape(g - 1, m_c, n_local)
         for j in range(g - 1):
             src = (me + 1 + j) % g
             out = lax.dynamic_update_slice(
@@ -172,16 +189,18 @@ def ficco_hetero_unfused_1d(
     n_local = w.shape[1]
     m_c = m_s // g
     out = jnp.zeros((g * m_s, n_local), dtype=jnp.result_type(x, w))
-    out = lax.dynamic_update_slice(
-        out, (x @ w).astype(out.dtype), (me * m_s, 0)
-    )
+    with _gemm():
+        local = (x @ w).astype(out.dtype)
+    out = lax.dynamic_update_slice(out, local, (me * m_s, 0))
     chunks = _chunk_rows(x, g)
     for s in range(g):
-        gathered = lax.all_gather(chunks[s], axis_name, axis=0)
+        with _exchange():
+            gathered = lax.all_gather(chunks[s], axis_name, axis=0)
         rolled = jnp.roll(gathered, -(me + 1), axis=0)
         for j in range(g - 1):
             src = (me + 1 + j) % g
-            piece = rolled[j] @ w  # (m_c, N_local): unfused chunk GEMM
+            with _gemm():
+                piece = rolled[j] @ w  # (m_c, N_local): unfused chunk GEMM
             out = lax.dynamic_update_slice(
                 out, piece.astype(out.dtype), (src * m_s + s * m_c, 0)
             )
@@ -204,10 +223,12 @@ def ficco_uniform_fused_2d(
     acc = jnp.zeros((g * m_s, n_local), dtype=jnp.float32)
     for s in range(g):
         chunk = lax.dynamic_slice(x, (0, s * k_c), (m_s, k_c))  # (m_s, K/g)
-        gathered = lax.all_gather(chunk, axis_name, axis=0)  # (g, m_s, K/g)
-        panel = gathered.reshape(g * m_s, k_c)  # Gather (rows contiguous)
+        with _exchange():
+            gathered = lax.all_gather(chunk, axis_name, axis=0)
+        panel = gathered.reshape(g * m_s, k_c)  # Gather (g, m_s, K/g)
         w_slice = lax.dynamic_slice(w, (s * k_c, 0), (k_c, n_local))
-        acc = acc + (panel @ w_slice).astype(jnp.float32)  # C += A_s @ B_s
+        with _gemm():
+            acc = acc + (panel @ w_slice).astype(jnp.float32)  # C += A_s B_s
     return acc.astype(jnp.result_type(x, w))
 
 

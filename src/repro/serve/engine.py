@@ -123,28 +123,34 @@ class DecodeEngine:
             max_prompt=max_prompt, max_new=max_new, **overlap_args,
         ):
             for pos in range(max_prompt + max_new):
-                feed = []
-                for r in reqs:
-                    if pos < len(r.prompt):
-                        feed.append(r.prompt[pos])
-                    elif r.out:
-                        feed.append(r.out[-1])
-                    else:
-                        feed.append(0)
-                tok = jnp.asarray(np.asarray(feed, np.int32)[:, None])
+                # A step's four phases, each a span: where the device
+                # waits on the host shows up under one of them.
                 with _trace.span("serve/step", "serve", pos=pos) as sp:
-                    logits, self.cache = self.step_fn(
-                        self.params, self.cache, tok, jnp.int32(pos)
-                    )
-                    nxt = np.asarray(jnp.argmax(logits[:, 0], -1))
-                    emitted = 0
-                    for i, r in enumerate(reqs[: len(requests)]):
-                        if (
-                            pos >= len(r.prompt) - 1
-                            and len(r.out) < r.max_new_tokens
-                        ):
-                            r.out.append(int(nxt[i]))
-                            emitted += 1
+                    with _trace.span("serve/feed", "serve"):
+                        feed = []
+                        for r in reqs:
+                            if pos < len(r.prompt):
+                                feed.append(r.prompt[pos])
+                            elif r.out:
+                                feed.append(r.out[-1])
+                            else:
+                                feed.append(0)
+                        tok = jnp.asarray(np.asarray(feed, np.int32)[:, None])
+                    with _trace.span("serve/dispatch", "serve"):
+                        logits, self.cache = self.step_fn(
+                            self.params, self.cache, tok, jnp.int32(pos)
+                        )
+                    with _trace.span("serve/fetch", "serve"):
+                        nxt = np.asarray(jnp.argmax(logits[:, 0], -1))
+                    with _trace.span("serve/emit", "serve"):
+                        emitted = 0
+                        for i, r in enumerate(reqs[: len(requests)]):
+                            if (
+                                pos >= len(r.prompt) - 1
+                                and len(r.out) < r.max_new_tokens
+                            ):
+                                r.out.append(int(nxt[i]))
+                                emitted += 1
                     sp.set(tokens=emitted)
                 steps_c.inc()
                 tokens_c.inc(emitted)

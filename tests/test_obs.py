@@ -101,6 +101,128 @@ class TestTracer:
         assert obs_trace.validate_trace({}) != []
 
 
+def _profile(tmp_path, body):
+    """Run ``body`` under a CPU ``jax.profiler`` session; returns the
+    host plane's events as (line, name, start_ns, end_ns, stats)."""
+    import glob
+
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host"):
+            for line in plane.lines:
+                out.extend((line.name, e.name, e.start_ns, e.end_ns,
+                            dict(e.stats)) for e in line.events)
+    return out
+
+
+class TestProfilerSink:
+    """With the profiler sink on, the spans the program opens land in the
+    JAX profiler's trace, on the clock of the ops they launch."""
+
+    def test_spans_reach_the_profiler_trace_beside_the_ops(self, tmp_path):
+        import jax
+        import jax.numpy as jnp
+
+        f = jax.jit(lambda x: (x @ x).sum())
+        x = jnp.ones((128, 128))
+        f(x).block_until_ready()
+
+        def body():
+            with obs_trace.span("serve/step", "serve", pos=3) as sp:
+                with obs_trace.span("serve/dispatch", "serve"):
+                    f(x).block_until_ready()
+                sp.set(tokens=4)
+
+        obs_trace.enable_profiler()
+        try:
+            events = _profile(tmp_path, body)
+        finally:
+            obs_trace.disable_profiler()
+        spans = {name: (s, e, stats) for _, name, s, e, stats in events
+                 if name.startswith("serve/")}
+        assert set(spans) == {"serve/step", "serve/dispatch"}
+        step, dispatch = spans["serve/step"], spans["serve/dispatch"]
+        # args given at entry and set after it are both kept
+        assert step[2]["pos"] == 3 and step[2]["tokens"] == 4
+        assert step[0] <= dispatch[0] <= dispatch[1] <= step[1]
+        ops = [(s, e) for _, _, s, e, stats in events if "hlo_op" in stats]
+        assert ops, "the CPU trace holds the jitted call's ops"
+        assert all(dispatch[0] <= s and e <= dispatch[1] for s, e in ops)
+
+    def test_off_records_nothing_and_returns_the_shared_noop(self):
+        obs_trace.enable_profiler()
+        assert obs_trace.profiler_enabled()
+        assert not obs_trace.enabled() and obs_trace.get_tracer() is None
+        with obs_trace.span("x") as sp:
+            assert sp is not obs_trace.NULL_SPAN
+        obs_trace.disable_profiler()
+        assert not obs_trace.profiler_enabled()
+        assert obs_trace.span("x", "t", a=1) is obs_trace.NULL_SPAN
+        assert obs_trace._TRACER is None
+
+    def test_json_sink_output_is_unchanged_by_the_profiler_sink(self):
+        def record(profiler: bool):
+            if profiler:
+                obs_trace.enable_profiler()
+            tr = obs_trace.enable()
+            with obs_trace.span("work", "cat", foo=1) as sp:
+                sp.set(bar=2)
+            obs_trace.instant("mark", note="here")
+            obs_trace.counter("rate", 3.5)
+            obs_trace.disable()
+            assert obs_trace.profiler_enabled() == profiler
+            obs_trace.disable_profiler()
+            assert obs_trace._TRACER is None
+            return [{k: v for k, v in e.items() if k not in ("ts", "dur")}
+                    for e in tr.events]
+
+        plain = record(False)
+        assert record(True) == plain
+        assert [e["name"] for e in plain] == ["work", "mark", "rate"]
+        assert plain[0]["args"] == {"foo": 1, "bar": 2}
+
+    def test_engine_step_phases_nest_in_the_step(self):
+        import jax
+
+        from repro.configs import get_config
+        from repro.models.model import build_model
+        from repro.serve.engine import DecodeEngine, Request
+
+        cfg = get_config("smollm-360m").reduced()
+        params = build_model(cfg).init(jax.random.PRNGKey(0))
+        eng = DecodeEngine(cfg, params, batch_size=2, cache_len=16)
+        tr = obs_trace.enable()
+        eng.run([Request(np.asarray([1, 2], np.int32), max_new_tokens=2)])
+        obs_trace.disable()
+        by = {}
+        for e in tr.events:
+            by.setdefault(e["name"], []).append(e)
+        phases = ("serve/feed", "serve/dispatch", "serve/fetch",
+                  "serve/emit")
+        steps = by["serve/step"]
+        assert len(by["serve/run"]) == 1 and len(steps) == 3
+        assert [s["args"]["tokens"] for s in steps] == [0, 1, 1]
+        for name in phases:
+            assert len(by[name]) == len(steps)
+        for i, st in enumerate(steps):
+            t0, t1 = st["ts"], st["ts"] + st["dur"]
+            inner = [by[name][i] for name in phases]
+            assert all(t0 <= p["ts"] and p["ts"] + p["dur"] <= t1
+                       for p in inner)
+            assert all(a["ts"] + a["dur"] <= b["ts"]
+                       for a, b in zip(inner, inner[1:]))
+
+
 class TestMetrics:
     def test_counter_histogram_snapshot(self):
         reg = obs_metrics.MetricsRegistry()
