@@ -203,8 +203,8 @@ def main():
 
             orig = shmod.cache_leaf_spec
 
-            def batch_only(shape, mesh):
-                sp = orig(shape, mesh)
+            def batch_only(shape, mesh, **kw):
+                sp = orig(shape, mesh, **kw)
                 entries = [
                     e if (isinstance(e, tuple) and "model" not in e)
                     or (e != "model")
@@ -222,16 +222,16 @@ def main():
 
             orig = shmod.cache_leaf_spec
 
-            def headdim(shape, mesh):
+            def headdim(shape, mesh, **kw):
                 model = mesh.shape.get("model", 1)
                 if len(shape) == 5 and shape[-1] % model == 0:
-                    # (periods, B, S, KV, hd): batch + head_dim sharding
-                    sp = list(orig(shape, mesh))
+                    # (periods, S, KV, B, hd): batch + head_dim sharding
+                    sp = list(orig(shape, mesh, **kw))
                     sp += [None] * (5 - len(sp))
-                    sp[2] = None  # drop time-axis sharding
+                    sp[1] = None  # drop time-axis sharding
                     sp[4] = "model"
                     return P(*sp)
-                return orig(shape, mesh)
+                return orig(shape, mesh, **kw)
 
             shmod.cache_leaf_spec = headdim
             undo = lambda: setattr(shmod, "cache_leaf_spec", orig)

@@ -45,6 +45,7 @@ from repro.parallel.sharding import (  # noqa: E402
 )
 from repro.roofline import analysis as roofline  # noqa: E402
 from repro.roofline import counters  # noqa: E402
+from repro.serve.engine import SERVE_STEP_DONATE  # noqa: E402
 from repro.train import optimizer as opt  # noqa: E402
 from repro.train.loop import make_train_step  # noqa: E402
 
@@ -146,7 +147,9 @@ def _build_jitted(cfg, shape, mesh, accum_steps: int = 1):
             args = (param_shapes, batch_shapes)
         else:  # decode
             dspec = specmod.decode_specs(cfg, shape, model)
-            cspecs = cache_specs(dspec["cache"], mesh)
+            cspecs = cache_specs(
+                dspec["cache"], mesh, model.cache_batch_dims(dspec["cache"])
+            )
             tspec = _batch_specs({"tokens": dspec["tokens"]}, mesh)["tokens"]
 
             def serve_step(params, cache, tokens, pos):
@@ -155,6 +158,7 @@ def _build_jitted(cfg, shape, mesh, accum_steps: int = 1):
 
             jitted = jax.jit(
                 serve_step,
+                donate_argnums=SERVE_STEP_DONATE,
                 in_shardings=(
                     _named(mesh, pspecs),
                     _named(mesh, cspecs),

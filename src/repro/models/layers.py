@@ -9,7 +9,8 @@ with explicit shard_map schedules (see parallel/tp.py).
 Attention is doubly-blocked (scan over query blocks, scan over KV blocks
 with online softmax) so 32k-token prefill fits per-device memory; the same
 code handles full-causal and sliding-window masks.  Decode uses a KV cache
-(ring buffer when a sliding window is configured).
+(ring buffer when a sliding window is configured) stacked over layers and
+written one token at a time in place.
 """
 
 from __future__ import annotations
@@ -23,6 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro.obs import metrics as _metrics
 from repro.parallel.sharding import BATCH_AXES, MODEL_AXIS, constrain
 
 
@@ -194,35 +196,86 @@ def blockwise_attention(
     return out[:, :sq].astype(q.dtype)
 
 
+# A layer's KV cache is (S, KV, B, D): slot, KV head, batch, head dim.
+# The TPU compiler reads a decode step's cache in that order as stored,
+# for one query head per KV head and for grouped ones (found by
+# compiling the step for a v5e at head dim 128); a (B, S, KV, D) or
+# (B, KV, S, D) cache it relays out, whole, every step.
+KV_CACHE_BATCH_AXIS = 2
+
+
+def kv_cache_shape(dims: AttnDims, batch: int, slots: int) -> tuple:
+    """One layer's KV cache shape, (S, KV, B, D)."""
+    return (slots, dims.num_kv_heads, batch, dims.head_dim)
+
+
 def cache_attention(
     q: jax.Array,
     k_cache: jax.Array,
     v_cache: jax.Array,
-    valid_len: jax.Array,
-    *,
-    ring: bool = False,
+    valid_len: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Single-token decode attention over a (B, S, KV, D) cache.
+    """Single-token decode attention over one layer's (S, KV, B, D) cache.
 
-    ``valid_len`` - number of valid cache entries (scalar).  With ``ring``
-    the whole buffer is valid (sliding-window ring cache, already full).
+    q: (B, 1, H, D).  ``valid_len`` - number of leading slots that hold
+    keys (scalar); None where every slot does (cross-attention).
     """
-    b, one, h, d = q.shape
-    s = k_cache.shape[1]
-    kv = k_cache.shape[2]
-    rep = h // kv
-    kr = jnp.repeat(k_cache, rep, axis=2)
-    vr = jnp.repeat(v_cache, rep, axis=2)
-    kr = constrain(kr, BATCH_AXES, "data" if b == 1 else None, None, None)
+    b, _, h, d = q.shape
+    s, kv = k_cache.shape[0], k_cache.shape[1]
+    # each KV head serves h // kv query heads (GQA)
+    qg = q.reshape(b, kv, h // kv, d)
+    k_cache = constrain(
+        k_cache, "data" if b == 1 else None, None, BATCH_AXES, None
+    )
     scores = jnp.einsum(
-        "bqhd,bkhd->bhqk", q.astype(jnp.float32), kr.astype(jnp.float32)
+        "bgrd,sgbd->bgrs", qg.astype(jnp.float32),
+        k_cache.astype(jnp.float32),
     ) / math.sqrt(d)
-    if not ring:
-        valid = jnp.arange(s)[None, None, None, :] < valid_len
-        scores = jnp.where(valid, scores, _NEG_INF)
+    if valid_len is not None:
+        scores = jnp.where(jnp.arange(s) < valid_len, scores, _NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, vr.astype(jnp.float32))
-    return out.astype(q.dtype)
+    out = jnp.einsum("bgrs,sgbd->bgrd", p, v_cache.astype(jnp.float32))
+    return out.reshape(b, 1, h, v_cache.shape[-1]).astype(q.dtype)
+
+
+def to_cache_order(x: jax.Array) -> jax.Array:
+    """(B, S, KV, D) keys or values, as projected, as (S, KV, B, D)."""
+    return x.transpose(1, 2, 0, 3)
+
+
+# ---------------------------------------------------------------------------
+# Decode caches stacked over layers
+# ---------------------------------------------------------------------------
+# The decode step carries each cache leaf as one (layers, ...) buffer
+# (donated by the serving engine).  A layer writes one token's row into
+# it in place and reads its own layer where it lies; a recurrent state is
+# replaced whole.  Each write counts its path at trace time
+# (``decode/cache_write.token`` or ``decode/cache_write.state``).
+
+
+def cache_layer(stack: jax.Array, layer) -> jax.Array:
+    """Layer ``layer`` of a cache leaf stacked over layers."""
+    return lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+
+
+def write_token(stack: jax.Array, layer, axis: int, slot,
+                row: jax.Array) -> jax.Array:
+    """``stack`` with one token's ``row`` (the layer's cache shape, 1 at
+    ``axis``) written at ``slot`` of the layer's ``axis``."""
+    _metrics.get_metrics().counter("decode/cache_write.token").inc()
+    start = [0] * stack.ndim
+    start[0], start[axis + 1] = layer, slot
+    return lax.dynamic_update_slice(
+        stack, row[None].astype(stack.dtype), start
+    )
+
+
+def write_state(stack: jax.Array, layer, state: jax.Array) -> jax.Array:
+    """``stack`` with layer ``layer`` replaced by ``state``."""
+    _metrics.get_metrics().counter("decode/cache_write.state").inc()
+    return lax.dynamic_update_index_in_dim(
+        stack, state.astype(stack.dtype), layer, 0
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -299,13 +352,16 @@ def attn_decode(
     params,
     x: jax.Array,
     cache: dict,
+    layer,
     pos: jax.Array,
     dims: AttnDims,
     *,
     rope_theta: float,
     window: Optional[int] = None,
 ):
-    """One-token decode. x: (B, 1, d); cache: {"k","v"} (B, S, KV, D)."""
+    """One-token decode. x: (B, 1, d); cache: {"k","v"} stacked over
+    layers, (L, S, KV, B, D); ``layer`` indexes this layer's entry.
+    Returns (y, {"k","v"}) with this token's keys and values written."""
     from repro.parallel.context import get_overlap
 
     b, one, _ = x.shape
@@ -317,41 +373,46 @@ def attn_decode(
             v = (x @ params["wv"]).reshape(b, 1, kv, hd)
             posv = jnp.full((b, 1), pos)
             q = apply_rope(q, posv, rope_theta)
-            k = apply_rope(k, posv, rope_theta)
+            k = to_cache_order(apply_rope(k, posv, rope_theta))
+            v = to_cache_order(v)
 
         ov = get_overlap()
         if (ov is not None
                 and getattr(ov, "decode_attn", "gspmd") == "shard_map"):
             from repro.parallel import decode_attn
 
-            if decode_attn.applicable(cache["k"], window):
+            k_layer = cache_layer(cache["k"], layer)
+            if decode_attn.applicable(k_layer, window):
                 with jax.named_scope("core"):
-                    out, k_cache, v_cache = (
+                    out, k_layer, v_layer = (
                         decode_attn.shard_map_attn_decode(
-                            q, k, v, cache["k"], cache["v"], pos
+                            q, k, v, k_layer,
+                            cache_layer(cache["v"], layer), pos,
                         )
                     )
+                with jax.named_scope("kv_cache"):
+                    # the time-sharded cache takes a masked write of the
+                    # whole layer
+                    new = {"k": write_state(cache["k"], layer, k_layer),
+                           "v": write_state(cache["v"], layer, v_layer)}
                 with jax.named_scope("o"):
                     y = out.reshape(b, 1, h * hd) @ params["wo"]
-                return y, {"k": k_cache, "v": v_cache}
+                return y, new
 
         with jax.named_scope("kv_cache"):
             s_cache = cache["k"].shape[1]
             slot = pos % s_cache if window is not None else pos
-            k_cache = lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0)
-            )
-            v_cache = lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0)
-            )
+            new = {"k": write_token(cache["k"], layer, 0, slot, k),
+                   "v": write_token(cache["v"], layer, 0, slot, v)}
         with jax.named_scope("core"):
+            # a ring holds the last s_cache positions once it is full
             out = cache_attention(
-                q, k_cache, v_cache, valid_len=pos + 1,
-                ring=window is not None,
+                q, cache_layer(new["k"], layer), cache_layer(new["v"], layer),
+                valid_len=jnp.minimum(pos + 1, s_cache),
             )
         with jax.named_scope("o"):
             y = out.reshape(b, 1, h * hd) @ params["wo"]
-        return y, {"k": k_cache, "v": v_cache}
+        return y, new
 
 
 # ---------------------------------------------------------------------------

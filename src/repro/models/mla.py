@@ -13,7 +13,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MLAConfig
@@ -116,24 +115,32 @@ def mla_decode(
     params,
     x: jax.Array,
     cache: dict,
+    layer,
     pos,
     num_heads: int,
     cfg: MLAConfig,
 ):
+    """One-token decode. cache: {"c_kv", "k_rope"} stacked over layers;
+    ``layer`` indexes this layer's entry, into which this token's latent
+    and rope key are written."""
     b = x.shape[0]
     posv = jnp.full((b, 1), pos)
     q, c_kv_new, k_rope_new = _project(params, x, num_heads, cfg, posv)
-    c_kv = lax.dynamic_update_slice(
-        cache["c_kv"], c_kv_new.astype(cache["c_kv"].dtype), (0, pos, 0)
-    )
-    k_rope = lax.dynamic_update_slice(
-        cache["k_rope"],
-        k_rope_new.astype(cache["k_rope"].dtype),
-        (0, pos, 0, 0),
-    )
+    cache = {
+        "c_kv": layers.write_token(cache["c_kv"], layer, 1, pos, c_kv_new),
+        "k_rope": layers.write_token(
+            cache["k_rope"], layer, 1, pos, k_rope_new
+        ),
+    }
     # Up-project the whole latent cache for this step's attention (the
     # recompute trade MLA makes for its 1/~10x cache size).
-    k, v = _expand_kv(params, c_kv, k_rope, num_heads, cfg)
-    out = cache_attention(q, k, v, valid_len=pos + 1)
+    k, v = _expand_kv(
+        params, layers.cache_layer(cache["c_kv"], layer),
+        layers.cache_layer(cache["k_rope"], layer), num_heads, cfg,
+    )
+    out = cache_attention(
+        q, layers.to_cache_order(k), layers.to_cache_order(v),
+        valid_len=pos + 1,
+    )
     y = out.reshape(b, 1, num_heads * cfg.v_head_dim) @ params["wo"]
-    return y, {"c_kv": c_kv, "k_rope": k_rope}
+    return y, cache

@@ -232,12 +232,9 @@ def _layer_init_cache(
     spec: LayerSpec, cfg: ModelConfig, batch: int, cache_len: int, dt
 ):
     if spec.mixer in ("attn",):
-        kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
         s = min(cache_len, cfg.sliding_window or cache_len)
-        return {
-            "k": jnp.zeros((batch, s, kv, hd), dt),
-            "v": jnp.zeros((batch, s, kv, hd), dt),
-        }
+        shape = layers.kv_cache_shape(_attn_dims(cfg), batch, s)
+        return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
     if spec.mixer == "mla":
         return mla.mla_init_cache(batch, cache_len, cfg.mla, dt)
     if spec.mixer == "mamba":
@@ -248,26 +245,41 @@ def _layer_init_cache(
     return xlstm.slstm_init_cache(batch, cfg.d_model, cfg.xlstm)
 
 
-def _layer_decode(p, spec: LayerSpec, cfg: ModelConfig, x, cache, pos,
-                  *, has_cross: bool = False):
+_CROSS = ("cross_k", "cross_v")
+
+
+def _layer_decode(p, spec: LayerSpec, cfg: ModelConfig, x, cache, layer,
+                  pos, *, has_cross: bool = False):
+    """One layer's decode step.  ``cache`` holds this pattern position's
+    leaves stacked over periods and ``layer`` indexes them: attention
+    caches take this token's row in place, recurrent states are replaced
+    whole, cross-attention K/V are only read."""
     h = layers.apply_norm(p["norm1"], x, cfg.norm)
     if spec.mixer == "attn":
-        y, cache2 = layers.attn_decode(
-            p["attn"], h, cache, pos, _attn_dims(cfg),
+        y, written = layers.attn_decode(
+            p["attn"], h, cache, layer, pos, _attn_dims(cfg),
             rope_theta=cfg.rope_theta, window=_window(cfg),
         )
     elif spec.mixer == "mla":
-        y, cache2 = mla.mla_decode(
-            p["attn"], h, cache, pos, cfg.num_heads, cfg.mla
-        )
-    elif spec.mixer == "mamba":
-        y, cache2 = mamba.mamba_decode(p["mixer"], h, cache, cfg.hybrid.mamba)
-    elif spec.mixer == "mlstm":
-        y, cache2 = xlstm.mlstm_decode(
-            p["mixer"], h, cache, cfg.num_heads, cfg.xlstm
+        y, written = mla.mla_decode(
+            p["attn"], h, cache, layer, pos, cfg.num_heads, cfg.mla
         )
     else:
-        y, cache2 = xlstm.slstm_decode(p["mixer"], h, cache, cfg.xlstm)
+        state = {k: layers.cache_layer(a, layer)
+                 for k, a in cache.items() if k not in _CROSS}
+        if spec.mixer == "mamba":
+            y, state = mamba.mamba_decode(
+                p["mixer"], h, state, cfg.hybrid.mamba
+            )
+        elif spec.mixer == "mlstm":
+            y, state = xlstm.mlstm_decode(
+                p["mixer"], h, state, cfg.num_heads, cfg.xlstm
+            )
+        else:
+            y, state = xlstm.slstm_decode(p["mixer"], h, state, cfg.xlstm)
+        written = {k: layers.write_state(cache[k], layer, a)
+                   for k, a in state.items()}
+    cache = {**cache, **written}
     x = x + y
     if has_cross:
         h = layers.apply_norm(p["norm_cross"], x, cfg.norm)
@@ -276,13 +288,10 @@ def _layer_decode(p, spec: LayerSpec, cfg: ModelConfig, x, cache, pos,
         q = (h @ p["cross"]["wq"]).reshape(b, 1, dims.num_heads,
                                            dims.head_dim)
         out = layers.cache_attention(
-            q, cache["cross_k"], cache["cross_v"],
-            valid_len=cache["cross_k"].shape[1], ring=True,
+            q, layers.cache_layer(cache["cross_k"], layer),
+            layers.cache_layer(cache["cross_v"], layer),
         )
         y = out.reshape(b, 1, -1) @ p["cross"]["wo"]
-        cache2 = dict(cache2)
-        cache2["cross_k"] = cache["cross_k"]
-        cache2["cross_v"] = cache["cross_v"]
         x = x + y
     if spec.ffn != "none":
         h = layers.apply_norm(p["norm2"], x, cfg.norm)
@@ -291,7 +300,7 @@ def _layer_decode(p, spec: LayerSpec, cfg: ModelConfig, x, cache, pos,
         else:
             y = layers.mlp_apply(p["ffn"], h)
         x = x + y
-    return x, cache2
+    return x, cache
 
 
 # ---------------------------------------------------------------------------
@@ -520,16 +529,24 @@ class Model:
                 for s in self.pattern
             ]
             if self.is_encdec:
-                kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+                shape = layers.kv_cache_shape(_attn_dims(cfg), batch, enc_len)
                 for c in caches:
-                    c["cross_k"] = jnp.zeros((batch, enc_len, kv, hd), dt)
-                    c["cross_v"] = jnp.zeros((batch, enc_len, kv, hd), dt)
+                    c["cross_k"] = jnp.zeros(shape, dt)
+                    c["cross_v"] = jnp.zeros(shape, dt)
             return caches
 
         one = period_cache()
         return jax.tree.map(
             lambda a: jnp.broadcast_to(a, (self.n_periods, *a.shape)), one
         )
+
+    def cache_batch_dims(self, cache):
+        """For each leaf of ``cache`` (as ``init_cache`` makes it), the
+        dimension that holds the batch, after the periods dimension: the
+        K/V caches hold it where their layout puts it, the rest first."""
+        kv = 1 + layers.KV_CACHE_BATCH_AXIS
+        return [{name: kv if name in ("k", "v") + _CROSS else 1
+                 for name in c} for c in cache]
 
     def prefill_cross(self, params, cache, enc_frames):
         """Enc-dec: run the encoder and fill per-layer cross K/V."""
@@ -547,8 +564,10 @@ class Model:
                 v = (enc_out @ pa["wv"]).reshape(
                     b, s_enc, dims.num_kv_heads, dims.head_dim
                 )
-                period_cache[i]["cross_k"] = k.astype(_dtype(cfg))
-                period_cache[i]["cross_v"] = v.astype(_dtype(cfg))
+                period_cache[i]["cross_k"] = layers.to_cache_order(
+                    k).astype(_dtype(cfg))
+                period_cache[i]["cross_v"] = layers.to_cache_order(
+                    v).astype(_dtype(cfg))
             return period_cache
 
         def body(_, args):
@@ -559,38 +578,39 @@ class Model:
         return new_cache
 
     def decode_step(self, params, cache, tokens, pos):
-        """tokens: (B, 1) int32; pos: scalar position. -> (logits, cache)."""
+        """tokens: (B, 1) int32; pos: scalar position. -> (logits, cache).
+
+        The layer loop carries the whole cache: each layer writes this
+        token's row into its entry in place and reads its entry where it
+        lies, so a caller that donates ``cache`` keeps one buffer."""
         cfg = self.config
         with jax.named_scope("embed"):
             x = params["embed"][tokens].astype(_dtype(cfg))
 
-        def body(x, args):
-            pp, pc = args
-            new_pc = []
+        def body(carry, args):
+            x, cache = carry
+            pp, layer = args
+            cache = list(cache)
             for i, spec in enumerate(self.pattern):
-                x, c2 = _layer_decode(
-                    pp[i], spec, cfg, x, pc[i], pos,
-                    has_cross="cross_k" in pc[i],
+                x, cache[i] = _layer_decode(
+                    pp[i], spec, cfg, x, cache[i], layer, pos,
+                    has_cross="cross_k" in cache[i],
                 )
-                new_pc.append(c2)
-            return x, new_pc
+            return (x, cache), None
 
         with jax.named_scope("layers"):
             if cfg.scan_layers and self.n_periods > 1:
-                x, new_cache = lax.scan(body, x, (params["layers"], cache))
+                (x, cache), _ = lax.scan(
+                    body, (x, cache),
+                    (params["layers"], jnp.arange(self.n_periods)),
+                )
             else:
-                new_caches = []
                 for i in range(self.n_periods):
                     pp = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-                    pc = jax.tree.map(lambda a, i=i: a[i], cache)
-                    x, npc = body(x, (pp, pc))
-                    new_caches.append(npc)
-                new_cache = jax.tree.map(
-                    lambda *xs: jnp.stack(xs), *new_caches
-                )
+                    (x, cache), _ = body((x, cache), (pp, i))
         x = layers.apply_norm(params["final_norm"], x, cfg.norm)
         logits = self._unembed(params, x)
-        return logits, new_cache
+        return logits, cache
 
 
 def build_model(config: ModelConfig) -> Model:

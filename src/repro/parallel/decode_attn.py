@@ -35,6 +35,7 @@ _NEG_INF = -1e30
 
 
 def applicable(k_cache: jax.Array, window) -> bool:
+    """Whether one layer's (S, KV, B, D) cache takes the explicit path."""
     mesh = _active_mesh()
     if mesh is None or MODEL_AXIS not in mesh.shape:
         return False
@@ -44,24 +45,24 @@ def applicable(k_cache: jax.Array, window) -> bool:
     return (
         g > 1
         and window is None
-        and k_cache.shape[1] % g == 0
-        and k_cache.shape[1] >= 1024
-        and k_cache.shape[0] % dp == 0
+        and k_cache.shape[0] % g == 0
+        and k_cache.shape[0] >= 1024
+        and k_cache.shape[2] % dp == 0
     )
 
 
 def shard_map_attn_decode(
     q: jax.Array,  # (B, 1, H, D) — post-RoPE
-    k_new: jax.Array,  # (B, 1, KV, D) — post-RoPE
-    v_new: jax.Array,  # (B, 1, KV, D)
-    k_cache: jax.Array,  # (B, S, KV, D), time-sharded over `model`
+    k_new: jax.Array,  # (1, KV, B, D) — post-RoPE
+    v_new: jax.Array,  # (1, KV, B, D)
+    k_cache: jax.Array,  # (S, KV, B, D), time-sharded over `model`
     v_cache: jax.Array,
     pos,  # scalar int32
 ):
     """Returns (out (B, 1, H, D), new_k_cache, new_v_cache)."""
     mesh = _active_mesh()
     g = mesh.shape[MODEL_AXIS]
-    b, s, kv, d = k_cache.shape
+    s, kv, _, d = k_cache.shape
     h = q.shape[2]
     s_loc = s // g
     batch_axes = tuple(a for a in BATCH_AXES if a in mesh.shape)
@@ -72,41 +73,39 @@ def shard_map_attn_decode(
         offset = me * s_loc
         local_idx = jnp.arange(s_loc)
         # masked in-place write (shard-local; no cross-device traffic)
-        write = (local_idx + offset == pos)[None, :, None, None]
+        write = (local_idx + offset == pos)[:, None, None, None]
         k_c = jnp.where(write, k_new.astype(k_c.dtype), k_c)
         v_c = jnp.where(write, v_new.astype(v_c.dtype), v_c)
 
-        rep = h // kv
-        kr = jnp.repeat(k_c, rep, axis=2)  # (B, s_loc, H, D)
-        vr = jnp.repeat(v_c, rep, axis=2)
+        bl = q.shape[0]
+        qg = q.reshape(bl, kv, h // kv, d)  # query heads by KV head
         scores = jnp.einsum(
-            "bqhd,bkhd->bhqk",
-            q.astype(jnp.float32),
-            kr.astype(jnp.float32),
+            "bgrd,sgbd->bgrs",
+            qg.astype(jnp.float32),
+            k_c.astype(jnp.float32),
         ) / math.sqrt(d)
         valid = (local_idx + offset <= pos)[None, None, None, :]
         scores = jnp.where(valid, scores, _NEG_INF)
-        m_loc = jnp.max(scores, -1)  # (B, H, 1)
+        m_loc = jnp.max(scores, -1)  # (B, KV, rep)
         p = jnp.exp(scores - m_loc[..., None])
         p = jnp.where(valid, p, 0.0)
-        l_loc = jnp.sum(p, -1)  # (B, H, 1)
-        o_loc = jnp.einsum("bhqk,bkhd->bqhd", p, vr.astype(jnp.float32))
+        l_loc = jnp.sum(p, -1)  # (B, KV, rep)
+        o_loc = jnp.einsum("bgrs,sgbd->bgrd", p, v_c.astype(jnp.float32))
 
         m_g = lax.pmax(m_loc, MODEL_AXIS)
         corr = jnp.exp(m_loc - m_g)
         l_g = lax.psum(l_loc * corr, MODEL_AXIS)
-        o_g = lax.psum(
-            o_loc * corr.transpose(0, 2, 1)[..., None], MODEL_AXIS
-        )
-        out = (o_g / jnp.maximum(l_g, 1e-30).transpose(0, 2, 1)[..., None])
-        return out.astype(q.dtype), k_c, v_c
+        o_g = lax.psum(o_loc * corr[..., None], MODEL_AXIS)
+        out = o_g / jnp.maximum(l_g, 1e-30)[..., None]
+        return out.reshape(bl, 1, h, d).astype(q.dtype), k_c, v_c
 
     rep_spec = P(bspec, None, None, None)
-    cache_spec = P(bspec, MODEL_AXIS, None, None)
+    new_spec = P(None, None, bspec, None)
+    cache_spec = P(MODEL_AXIS, None, bspec, None)
     return jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(rep_spec, rep_spec, rep_spec, cache_spec, cache_spec,
+        in_specs=(rep_spec, new_spec, new_spec, cache_spec, cache_spec,
                   P()),
         out_specs=(rep_spec, cache_spec, cache_spec),
         check_vma=False,

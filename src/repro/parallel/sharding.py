@@ -143,14 +143,15 @@ def fix_param_specs(specs, shapes, mesh) -> "object":
     )
 
 
-def cache_leaf_spec(shape, mesh) -> P:
+def cache_leaf_spec(shape, mesh, batch_dim: int) -> P:
     """Decode-cache sharding rule.
 
-    Layout (periods, B, ...): batch over (pod, data) when divisible; the
-    largest remaining dimension >= 1024 divisible by the model axis is
-    sharded over 'model' (the 32k KV time axis, or Mamba's d_inner);
-    when batch is unsharded (long_500k B=1) the 'data' axis joins the
-    sequence dimension — context-parallel cache reads.
+    Layout (periods, ...) with the batch at ``batch_dim``: batch over
+    (pod, data) when divisible; the largest remaining dimension >= 1024
+    divisible by the model axis is sharded over 'model' (the 32k KV time
+    axis, or Mamba's d_inner); when batch is unsharded (long_500k B=1)
+    the 'data' axis joins the sequence dimension — context-parallel cache
+    reads.
     """
     names = set(mesh.shape)
     rank = len(shape)
@@ -160,12 +161,16 @@ def cache_leaf_spec(shape, mesh) -> P:
     for a in batch_axes:
         dp *= mesh.shape[a]
     batch_sharded = False
-    if rank >= 2 and dp > 1 and shape[1] % dp == 0 and shape[1] >= dp:
-        entries[1] = batch_axes if len(batch_axes) > 1 else batch_axes[0]
+    if (rank >= 2 and dp > 1 and shape[batch_dim] % dp == 0
+            and shape[batch_dim] >= dp):
+        entries[batch_dim] = (
+            batch_axes if len(batch_axes) > 1 else batch_axes[0]
+        )
         batch_sharded = True
     model = mesh.shape.get(MODEL_AXIS, 1)
     rest = sorted(
-        range(2, rank), key=lambda i: shape[i], reverse=True
+        (i for i in range(1, rank) if i != batch_dim),
+        key=lambda i: shape[i], reverse=True,
     )
     model_used = False
     for i in rest:
@@ -184,7 +189,10 @@ def cache_leaf_spec(shape, mesh) -> P:
     return P(*entries)
 
 
-def cache_specs(cache_shapes, mesh):
+def cache_specs(cache_shapes, mesh, batch_dims):
+    """Specs of a decode cache; ``batch_dims`` (a tree like the cache,
+    ``Model.cache_batch_dims``) places each leaf's batch."""
     return jax.tree.map(
-        lambda l: cache_leaf_spec(l.shape, mesh), cache_shapes
+        lambda l, bd: cache_leaf_spec(l.shape, mesh, batch_dim=bd),
+        cache_shapes, batch_dims,
     )

@@ -21,6 +21,11 @@ from repro.obs import trace as _trace
 from repro.parallel.context import overlap_context
 
 
+# Arguments of the serve step that its jit donates: the cache, so the
+# step's in-place writes land in the buffer it was given.
+SERVE_STEP_DONATE = (1,)
+
+
 def make_serve_step(model: Model) -> Callable:
     """(params, cache, tokens (B,1), pos) -> (logits, new_cache)."""
 
@@ -74,7 +79,9 @@ class DecodeEngine:
         self.cache = self.model.init_cache(
             batch_size, cache_len, enc_len=enc_len
         )
-        self.step_fn = jax.jit(make_serve_step(self.model))
+        self.step_fn = jax.jit(
+            make_serve_step(self.model), donate_argnums=SERVE_STEP_DONATE
+        )
         # Online-adaptation tier (repro.serve.adapt.AdaptiveTier): when
         # set, every run() streams its request-load digest through the
         # tier and records the tuned overlap schedule for the batch.

@@ -242,10 +242,10 @@ def shard_map_decode_attn_matches_reference():
     rng = np.random.default_rng(7)
     b, s, h, kv, d = 4, 4096, 8, 4, 32
     q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
-    k_new = jnp.asarray(rng.standard_normal((b, 1, kv, d)), jnp.float32)
-    v_new = jnp.asarray(rng.standard_normal((b, 1, kv, d)), jnp.float32)
-    k_c = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
-    v_c = jnp.asarray(rng.standard_normal((b, s, kv, d)), jnp.float32)
+    k_new = jnp.asarray(rng.standard_normal((1, kv, b, d)), jnp.float32)
+    v_new = jnp.asarray(rng.standard_normal((1, kv, b, d)), jnp.float32)
+    k_c = jnp.asarray(rng.standard_normal((s, kv, b, d)), jnp.float32)
+    v_c = jnp.asarray(rng.standard_normal((s, kv, b, d)), jnp.float32)
     pos = jnp.int32(2500)
 
     with jax.sharding.set_mesh(mesh):
@@ -253,8 +253,8 @@ def shard_map_decode_attn_matches_reference():
             q, k_new, v_new, k_c, v_c, pos
         )
     # reference: dense update + cache_attention
-    k_ref = k_c.at[:, 2500].set(k_new[:, 0])
-    v_ref = v_c.at[:, 2500].set(v_new[:, 0])
+    k_ref = k_c.at[2500].set(k_new[0])
+    v_ref = v_c.at[2500].set(v_new[0])
     want = cache_attention(q, k_ref, v_ref, valid_len=pos + 1)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(want), rtol=2e-4, atol=2e-4

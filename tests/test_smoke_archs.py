@@ -16,6 +16,7 @@ from repro.configs import ARCHS, SHAPES, get_config
 from repro.configs.base import ShapeConfig
 from repro.launch.specs import concrete_batch, encoder_len
 from repro.models.model import build_model
+from repro.obs import metrics
 
 SMOKE_SHAPE = ShapeConfig("smoke", seq_len=32, global_batch=2, kind="train")
 
@@ -59,6 +60,20 @@ def test_grad_step(arch):
     assert np.isfinite(total) and total > 0
 
 
+def _expected_cache_writes(model, cache) -> dict:
+    """Trace-time counts of the decode step's cache writes: a row in
+    place for each attention / MLA leaf, a whole rewrite for each
+    recurrent leaf, nothing for the read-only cross-attention K/V; once
+    per traced layer (a scan traces its body once)."""
+    traced = 1 if model.config.scan_layers and model.n_periods > 1 else (
+        model.n_periods)
+    want = {"token": 0, "state": 0}
+    for spec, leaves in zip(model.pattern, cache):
+        n = sum(1 for name in leaves if not name.startswith("cross_"))
+        want["token" if spec.mixer in ("attn", "mla") else "state"] += n
+    return {k: v * traced for k, v in want.items()}
+
+
 @pytest.mark.parametrize("arch", sorted(ARCHS))
 def test_decode_step(arch):
     cfg = _reduced(arch)
@@ -71,11 +86,16 @@ def test_decode_step(arch):
         frames = jnp.zeros((2, enc_len, cfg.d_model), jnp.float32)
         cache = model.prefill_cross(params, cache, frames)
     tok = jnp.zeros((2, 1), jnp.int32)
+    metrics.reset_metrics()
     step = jax.jit(model.decode_step)
     logits, cache = step(params, cache, tok, jnp.int32(0))
     logits2, cache = step(params, cache, tok, jnp.int32(1))
     assert logits.shape == (2, 1, cfg.vocab_size)
     assert np.isfinite(np.asarray(logits2, np.float32)).all()
+    reg = metrics.get_metrics()
+    got = {k: reg.counter(f"decode/cache_write.{k}").value
+           for k in ("token", "state")}
+    assert got == _expected_cache_writes(model, cache)
 
 
 def test_decode_matches_forward_dense():
@@ -139,5 +159,57 @@ def test_sliding_window_limits_context():
     # ring cache: decode with a window-4 cache buffer
     cache = model.init_cache(1, 12)
     # cache[0] = layer-0 dict, leaves stacked over periods:
-    # (n_periods, B, ring_len, KV, hd)
-    assert cache[0]["k"].shape[2] == 4  # ring sized to the window
+    # (n_periods, ring_len, KV, B, hd)
+    assert cache[0]["k"].shape[1] == 4  # ring sized to the window
+
+
+def _decode_case(case):
+    """(config, sequence length, cache length) of a decode-vs-forward
+    case: a sliding window whose ring wraps, MLA's latent cache, attention
+    beside Mamba states, and cross-attention over an encoder's K/V."""
+    if case == "ring":
+        cfg = dataclasses.replace(
+            _reduced("tinyllama-1.1b"), sliding_window=4
+        )
+        return cfg, 12, 12
+    if case == "mla":
+        return dataclasses.replace(
+            _reduced("deepseek-v2-lite-16b"), moe=None
+        ), 8, 16
+    if case == "hybrid":
+        return dataclasses.replace(
+            _reduced("jamba-1.5-large-398b"), moe=None
+        ), 8, 16
+    return _reduced("seamless-m4t-large-v2"), 8, 16
+
+
+@pytest.mark.parametrize("case", ["ring", "mla", "hybrid", "encdec"])
+def test_decode_matches_forward_cache_kinds(case):
+    """Decode, writing each token into the cache in place, gives the
+    teacher-forced forward pass's logits for every kind of cache."""
+    cfg, n, cache_len = _decode_case(case)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(3))
+    toks = jnp.asarray(
+        np.random.default_rng(3).integers(0, cfg.vocab_size, (2, n)),
+        jnp.int32,
+    )
+    batch = {"tokens": toks}
+    cache = model.init_cache(2, cache_len, enc_len=8 if cfg.encdec else 0)
+    if cfg.encdec:
+        batch["enc_frames"] = jnp.asarray(
+            np.random.default_rng(4).standard_normal((2, 8, cfg.d_model)),
+            jnp.float32,
+        )
+        cache = model.prefill_cross(params, cache, batch["enc_frames"])
+    logits_fwd, _ = model.forward(params, batch)
+    step = jax.jit(model.decode_step)
+    for i in range(n):
+        logits_dec, cache = step(
+            params, cache, toks[:, i : i + 1], jnp.int32(i)
+        )
+        np.testing.assert_allclose(
+            np.asarray(logits_dec[:, 0], np.float32),
+            np.asarray(logits_fwd[:, i], np.float32),
+            rtol=2e-3, atol=2e-3,
+        )

@@ -1,6 +1,7 @@
 """Substrate tests: pipeline determinism, optimizer, checkpoint, training
 convergence on the synthetic task, serve engine."""
 
+import re
 import tempfile
 
 import jax
@@ -11,7 +12,7 @@ from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.data.pipeline import SyntheticLM, make_pipeline
 from repro.models.model import build_model
-from repro.serve.engine import DecodeEngine, Request
+from repro.serve.engine import DecodeEngine, Request, make_serve_step
 from repro.train import optimizer as opt
 from repro.train.loop import train
 from repro.ckpt.checkpoint import restore_checkpoint, save_checkpoint
@@ -96,6 +97,38 @@ def test_serve_engine_greedy():
     out = eng.run(reqs)
     assert all(len(r.out) == 4 for r in out)
     assert all(0 <= t < cfg.vocab_size for r in out for t in r.out)
+
+
+def test_serve_engine_donates_cache():
+    """The engine's step donates its cache: the compiled step aliases
+    every cache leaf to an output, the cache it was handed is gone after
+    a step, and greedy output over several batches equals an undonated
+    step's token for token."""
+    cfg = get_config("tinyllama-1.1b").reduced()
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = DecodeEngine(cfg, params, batch_size=2, cache_len=32)
+    text = eng.step_fn.lower(
+        params, eng.cache, jnp.zeros((2, 1), jnp.int32), jnp.int32(0)
+    ).compile().as_text()
+    header = text.split("entry_computation_layout")[0]
+    aliased = {int(p) for p in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    n_params = len(jax.tree.leaves(params))
+    n_cache = len(jax.tree.leaves(eng.cache))
+    assert aliased == set(range(n_params, n_params + n_cache))
+
+    plain = DecodeEngine(cfg, params, batch_size=2, cache_len=32)
+    plain.step_fn = jax.jit(make_serve_step(plain.model))
+    rng = np.random.default_rng(5)
+    for _ in range(3):
+        prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+                   for n in (5, 3)]
+        given = eng.cache
+        got = eng.run([Request(p, max_new_tokens=4) for p in prompts])
+        want = plain.run([Request(p, max_new_tokens=4) for p in prompts])
+        assert all(a.is_deleted() for a in jax.tree.leaves(given))
+        assert [r.out for r in got] == [r.out for r in want]
 
 
 def test_grad_accumulation_equivalence():

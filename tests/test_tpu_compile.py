@@ -1,15 +1,18 @@
-"""Compile the main-path Pallas kernels for a TPU v5e that is described,
-not attached.
+"""Compile the main-path Pallas kernels, and the decode step, for a TPU
+v5e that is described, not attached.
 
 Interpret mode accepts what the chip's compiler refuses (unaligned
 slices, scoped VMEM overruns), so the kernels are compiled here at the
 widths TinyLlama-1.1B uses at tensor parallelism 4: K = d_model = 2048,
 n_local = d_ff / 4 = 1408, shard rows m_s = tokens / 4.  Nothing runs;
-the compiler only accepts or refuses.
+the compiler only accepts or refuses.  The decode step is compiled at
+published widths to read what the chip's compiler makes of its cache.
 """
 
+import dataclasses
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,11 +21,14 @@ import pytest
 from jax.sharding import AxisType, Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.configs import get_config
 from repro.core.machine import TPU_V5E
 from repro.core.workload import GemmShape
 from repro.kernels.chunked_gemm import chunked_matmul
 from repro.kernels.dma_exchange import ficco_uniform_fused_1d_dma
 from repro.kernels.ficco_ag_matmul import ficco_ag_matmul_fused
+from repro.models.model import build_model
+from repro.serve.engine import SERVE_STEP_DONATE, make_serve_step
 from repro.tune import default_variant
 from repro.tune.prune import check_variant
 
@@ -128,3 +134,46 @@ def test_prune_refuses_what_the_compiler_refuses_for_vmem(mesh):
         _compile_ag(ficco_ag_matmul_fused, mesh, m_s, k, n_local, variant)
     fits = GemmShape(G * 1024, G * N_LOCAL, K, 2)
     assert check_variant(variant, fits, TPU_V5E, group=G) is None
+
+
+# one instruction of a compiled module: its name, result dims and opcode
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(?P<name>\S+) = \w+\[(?P<dims>[\d,]*)\]\S* "
+    r"(?P<op>[\w\-]+)\(", re.M)
+
+
+@pytest.mark.parametrize("arch", ["olmo-1b", "yi-9b"])
+def test_decode_step_keeps_its_cache_in_place(topo, arch):
+    """The serving step at published widths (two layers, a batch of 4
+    over 2,048 slots), one query head per KV head and grouped: the
+    donated cache is the step's output buffer, and no instruction copies
+    the cache or a layer of it (a relayout, a slice or a write-back),
+    as the step before the cache was written in place did."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    model = build_model(dataclasses.replace(get_config(arch), num_layers=2))
+    cache = jax.eval_shape(lambda: model.init_cache(4, 2048))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    compiled = jax.jit(
+        make_serve_step(model), donate_argnums=SERVE_STEP_DONATE
+    ).lower(
+        on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0))),
+        on_chip(cache),
+        jax.ShapeDtypeStruct((4, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+    leaves = jax.tree.leaves(cache)
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == cache_bytes
+    # no temporary holds as much as one layer's keys
+    assert memory.temp_size_in_bytes < cache_bytes / len(leaves) / 2
+    shapes = {",".join(map(str, a.shape[i:]))
+              for a in jax.tree.leaves(cache) for i in (0, 1)}
+    copies = [m.group(0) for m in _INSTRUCTION.finditer(compiled.as_text())
+              if m.group("dims") in shapes
+              and "copy" in (m.group("op"), m.group("name")[:4])]
+    assert not copies, copies
