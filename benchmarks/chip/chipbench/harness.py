@@ -6,13 +6,17 @@ metric is added by adding files and entries, never by editing this one:
 
 * ``BENCHMARK.json`` (the repository's root): the cell, its chips, and
   which end-to-end and per-layer metrics it reports;
-* ``configs/<config>.json``: the sizes as run, the overlap mode, the mesh;
+* ``configs/<config>.json``: the sizes as run, the overlap mode, the mesh,
+  and the ``family`` the sizes belong to;
+* ``families/<family>.py``: the family's sizes, the program's config
+  object, weights from the seed, the work a step requires and the plain
+  reference (``chipbench.family``);
 * ``traffic/<traffic>.json``: the mix, a data file that names its
   ``generator`` and its ``loop``;
 * ``generators/<generator>.py``: ``batch(mix, vocab, seed, i)``, the
   inputs of batch ``i`` made from the seed;
 * ``loops/<loop>.py``: ``Loop(ctx)``, which drives the program's timed
-  path (``chipbench.loops``);
+  path, and ``program(ctx)``, the program it times (``chipbench.loops``);
 * ``cells/<cell>.json``: the limits of the numbers that decide ``correct``;
 * ``metrics/<metric>.py``: a reader, ``read(run) -> float | None``.
 """
@@ -39,8 +43,8 @@ class NoChip(SystemExit):
 @dataclasses.dataclass
 class Files:
     """Where a benchmark's files live: ``root`` holds BENCHMARK.json and
-    ``bench`` the configs, traffic, generators, loops, cells and metrics
-    directories."""
+    ``bench`` the configs, families, traffic, generators, loops, cells
+    and metrics directories."""
 
     root: pathlib.Path
     bench: pathlib.Path = BENCH_DIR
@@ -77,23 +81,39 @@ class Files:
         spec = importlib.util.spec_from_file_location(
             f"chipbench_{sub}_{key}", path)
         mod = importlib.util.module_from_spec(spec)
+        # A dataclass looks its module up by name while it is made.
+        sys.modules[spec.name] = mod
         spec.loader.exec_module(mod)
         return mod
 
     def reader(self, metric: str):
         return self.module("metrics", metric).read
 
-    def make_loop(self, c: dict, seed: int, traced: bool = False):
-        """The loop that drives cell ``c`` (from :meth:`cell`), with its
-        mix's generator, for ``seed``."""
+    def family(self, config: dict):
+        """The module of the family a configuration file names."""
+        name = config.get("family")
+        if name is None:
+            raise SystemExit("no families file: the configuration names "
+                             "no family")
+        return self.module("families", name)
+
+    def context(self, c: dict, seed: int, traced: bool = False,
+                devices=None):
+        """What the loop of cell ``c`` (from :meth:`cell`) reads: its
+        files, its mix's generator and its configuration's family."""
         from chipbench import loops
 
         mix = c["traffic"]
-        ctx = loops.Context(c["entry"]["name"], c["config"], mix,
-                            c["limits"], seed, c["entry"]["chips"],
-                            self.module("generators", mix["generator"]),
-                            traced)
-        return self.module("loops", mix["loop"]).Loop(ctx)
+        return loops.Context(c["entry"]["name"], c["config"], mix,
+                             c["limits"], seed, c["entry"]["chips"],
+                             self.module("generators", mix["generator"]),
+                             self.family(c["config"]), traced, devices)
+
+    def make_loop(self, c: dict, seed: int, traced: bool = False):
+        """The loop that drives cell ``c`` (from :meth:`cell`) for
+        ``seed``."""
+        loop = self.module("loops", c["traffic"]["loop"])
+        return loop.Loop(self.context(c, seed, traced))
 
 
 @dataclasses.dataclass

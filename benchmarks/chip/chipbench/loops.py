@@ -6,8 +6,12 @@ A mix (``traffic/<mix>.json``) names its ``loop``, a file
 vocab, seed, i)`` makes the inputs of batch ``i`` from the seed.  The
 harness finds both by name, as it finds the metric readers.  Each loop:
 
-* ``setup()`` makes the weights on the device from the seed, builds the
-  program's compiled step, and runs every shape the window will use;
+* ``program(ctx)``, a function of the loop's module, builds the program
+  the loop times and returns ``(jitted fn, its arguments' shapes with
+  their shardings, the mesh to call it under or None)``; the scope
+  readers compile the same (``chipbench.scopes``);
+* ``setup()`` builds that program with ``program``, makes the weights on
+  the device from the seed, and runs every shape the window will use;
 * ``window(seconds)`` drives that same step in a closed loop until
   ``seconds`` have passed and the work in flight has finished, and
   returns its records and end-to-end metrics;
@@ -25,13 +29,12 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import numpy as np
 
-from chipbench import weights
-from chipbench.model import Dims
+from chipbench.family import seed_key
 
 span = jax.profiler.TraceAnnotation
 
@@ -45,11 +48,14 @@ class Context:
     seed: int
     chips: int
     gen: Any  # the mix's generator module
+    family: Any  # the configuration's family module (chipbench.family)
     traced: bool = False
+    # the devices a mesh is built from; None: jax.devices()
+    devices: Optional[list] = None
 
     @property
-    def dims(self) -> Dims:
-        return Dims.from_config(self.config)
+    def dims(self):
+        return self.family.dims(self.config)
 
     def batch(self, i: int):
         """The inputs of batch ``i``, from the mix's generator."""
@@ -60,12 +66,13 @@ def percentile(values, q: float) -> float:
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
-def make_params(model, m: Dims, seed: int, out_shardings=None):
-    """The seed's weights, made on the device in one jitted call, after
-    checking that they have the program's tree, shapes and dtypes."""
-    key = weights.seed_key(seed)
-    make = functools.partial(weights.program_params, m)
-    want = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+def make_params(ctx: Context, want):
+    """The seed's weights, made on the device in one jitted call with the
+    shardings of ``want``, the program's parameter shapes (from
+    ``program``), after checking that they have its tree, shapes and
+    dtypes."""
+    key = seed_key(ctx.seed)
+    make = functools.partial(ctx.family.program_params, ctx.dims)
     got = jax.eval_shape(make, key)
     if jax.tree.structure(want) != jax.tree.structure(got):
         raise ValueError("weights do not have the program's tree")
@@ -73,7 +80,9 @@ def make_params(model, m: Dims, seed: int, out_shardings=None):
         if (a.shape, a.dtype) != (b.shape, b.dtype):
             raise ValueError(f"leaf {a.shape} {a.dtype} != {b.shape} "
                              f"{b.dtype}")
-    return jax.jit(make, out_shardings=out_shardings)(key)
+    placed = all(a.sharding is not None for a in jax.tree.leaves(want))
+    shardings = jax.tree.map(lambda a: a.sharding, want) if placed else None
+    return jax.jit(make, out_shardings=shardings)(key)
 
 
 def _rel_gaps(prog: dict, ref: dict) -> dict:

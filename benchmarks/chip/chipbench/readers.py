@@ -24,7 +24,7 @@ EXCHANGE = re.compile(
 def step_mfu(run) -> float:
     """The whole window's share, in %, of the chips' roofline: the least
     time the work the steps required could take at the published peaks
-    (``chipbench.counters``), over the time the window took."""
+    (the family's counters), over the time the window took."""
     least, _ = run.records["work"].roofline_s(run.peaks, run.chips)
     return 100.0 * least / run.records["elapsed"]
 

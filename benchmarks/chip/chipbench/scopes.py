@@ -224,49 +224,16 @@ def result_dims(sig: str) -> str:
     return m.group("d") if m else ""
 
 
-def program(files, cell: str):
+def program(files, cell: str, devices=None):
     """The jitted callable the cell's loop times, its arguments' shapes
-    (with their shardings) and the mesh to compile it under, built from
-    the cell's files as ``loops/prefill.py`` and ``loops/decode.py``
-    build them; None for a loop of another kind."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding
-    from jax.sharding import PartitionSpec as P
-
-    from chipbench.model import program_config
-    from repro.models.model import build_model
-
+    (with their shardings) and the mesh to compile it under, as the loop
+    module's own ``program(ctx)`` builds them (a mesh of ``devices``
+    where given); None for a loop that has no ``program``."""
     c = files.cell(cell)
-    mix = c["traffic"]
-    model = build_model(program_config(c["config"]))
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    if mix["loop"] == "decode":
-        from repro.serve.engine import make_serve_step
-
-        cache = jax.eval_shape(
-            lambda: model.init_cache(mix["batch"], mix["cache_len"]))
-        args = (shapes, cache,
-                jax.ShapeDtypeStruct((mix["batch"], 1), jnp.int32),
-                jax.ShapeDtypeStruct((), jnp.int32))
-        return jax.jit(make_serve_step(model)), args, None
-    if mix["loop"] == "prefill":
-        from repro.launch.mesh import make_mesh
-        from repro.parallel.sharding import fix_param_specs
-        from repro.serve.engine import make_prefill
-
-        axes = c["config"]["mesh"]
-        mesh = make_mesh(tuple(axes.values()), tuple(axes))
-        specs = fix_param_specs(model.param_specs(), shapes, mesh)
-        params = jax.tree.map(
-            lambda a, s: jax.ShapeDtypeStruct(
-                a.shape, a.dtype, sharding=NamedSharding(mesh, s)),
-            shapes, specs)
-        toks = jax.ShapeDtypeStruct((mix["batch"], mix["seq_len"]),
-                                    jnp.int32,
-                                    sharding=NamedSharding(mesh, P()))
-        return jax.jit(make_prefill(model)), (params, {"tokens": toks}), mesh
-    return None
+    loop = files.module("loops", c["traffic"]["loop"])
+    if not hasattr(loop, "program"):
+        return None
+    return loop.program(files.context(c, 0, devices=devices))
 
 
 def compiled_text(fn, args, mesh=None) -> str:

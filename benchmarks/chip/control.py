@@ -74,18 +74,17 @@ def program_reading(files, cell: str, seed: int, seconds: float,
 def control_reading(files, cell: str, seed: int) -> dict:
     """A training cell's numbers with the float8 reference in the
     program's place."""
-    from chipbench import loops, reference
-    from chipbench.model import Dims
+    from chipbench import loops
 
     c = files.cell(cell)
-    loop = files.make_loop(c, seed)
-    m = Dims.from_config(c["config"])
-    batches = [loop.ctx.batch(i) for i in range(3)]
+    ctx = files.make_loop(c, seed).ctx
+    batches = [ctx.batch(i) for i in range(3)]
     opt = c["config"]["optimizer"]
     block = c["traffic"].get("reference_block", 1)
-    ref = reference.train_reference(m, seed, batches, opt, block=block)
-    ctl = reference.train_reference(m, seed, batches, opt, quant="fp8",
-                                    block=block)
+    ref = ctx.family.train_reference(ctx.dims, seed, batches, opt,
+                                     block=block)
+    ctl = ctx.family.train_reference(ctx.dims, seed, batches, opt,
+                                     quant="fp8", block=block)
 
     class Prog:
         losses = ctl["losses"]

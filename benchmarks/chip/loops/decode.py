@@ -8,13 +8,37 @@ from __future__ import annotations
 
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import counters, reference
+from chipbench.family import Work
 from chipbench.loops import (Context, StampedTokens, gap_numbers,
                              make_params, percentile, span)
-from chipbench.model import program_config
+
+
+def _engine(ctx: Context):
+    """The program's engine for the mix's batch and cache, without
+    weights."""
+    from repro.serve.engine import DecodeEngine
+
+    return DecodeEngine(ctx.family.program_config(ctx.config), None,
+                        batch_size=ctx.mix["batch"],
+                        cache_len=ctx.mix["cache_len"])
+
+
+def program(ctx: Context, engine=None):
+    """The engine's own jitted step (its cache donated, as the engine
+    jits it), with the shapes of its weights, cache, tokens and position;
+    ``engine`` is the one to take it from, else a new one."""
+    eng = engine or _engine(ctx)
+    params = jax.eval_shape(eng.model.init, jax.random.PRNGKey(0))
+    cache = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                         eng.cache)
+    args = (params, cache,
+            jax.ShapeDtypeStruct((eng.batch, 1), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32))
+    return eng.step_fn, args, None
 
 
 class Loop:
@@ -26,15 +50,12 @@ class Loop:
         self.mix = ctx.mix
 
     def setup(self) -> None:
-        from repro.models.model import build_model
-        from repro.serve.engine import DecodeEngine, Request
+        from repro.serve.engine import Request
 
         self.Request = Request
-        m = self.dims
-        cfg = program_config(self.ctx.config)
-        params = make_params(build_model(cfg), m, self.ctx.seed)
-        self.engine = DecodeEngine(cfg, params, batch_size=self.mix["batch"],
-                                   cache_len=self.mix["cache_len"])
+        self.engine = _engine(self.ctx)
+        _, args, _ = program(self.ctx, self.engine)
+        self.engine.params = make_params(self.ctx, args[0])
         if self.ctx.traced:
             inner = self.engine.step_fn
 
@@ -52,7 +73,7 @@ class Loop:
 
     def window(self, seconds: float) -> dict:
         m = self.dims
-        work, steps, batches = counters.Work(), 0, 0
+        work, steps, batches = Work(), 0, 0
         t0 = time.perf_counter()
         while time.perf_counter() - t0 < seconds:
             reqs = [
@@ -62,7 +83,7 @@ class Loop:
             with span("bench/engine.run"):
                 self.engine.run(reqs)
             n_steps = max(len(r.prompt) + r.max_new_tokens - 1 for r in reqs)
-            work = work + counters.decode_batch(
+            work = work + self.ctx.family.decode_batch(
                 m, [len(r.prompt) for r in reqs],
                 [r.max_new_tokens for r in reqs], n_steps)
             steps += n_steps
@@ -121,7 +142,7 @@ class Loop:
             blocks.append(jnp.asarray(seq[None]))
             chosen.append(jnp.asarray(ch[None]))
             masks.append(mask[None])
-        gaps, cgaps = reference.token_gaps(self.dims, self.ctx.seed, blocks,
-                                           chosen, control=control)
+        gaps, cgaps = self.ctx.family.token_gaps(
+            self.dims, self.ctx.seed, blocks, chosen, control=control)
         return gap_numbers(gaps, cgaps, masks, control,
                             self.ctx.limits["limits"]["token_gap"])

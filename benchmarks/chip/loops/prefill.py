@@ -10,9 +10,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import counters, reference
+from chipbench.family import Work
 from chipbench.loops import Context, gap_numbers, make_params, span
-from chipbench.model import program_config
+
+
+def program(ctx: Context):
+    """``make_prefill`` jitted, with the shapes of the weights, sharded as
+    the program shards them on the configuration's mesh, and of the
+    replicated tokens."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.launch.mesh import make_mesh
+    from repro.models.model import build_model
+    from repro.parallel.sharding import fix_param_specs
+    from repro.serve.engine import make_prefill
+
+    model = build_model(ctx.family.program_config(ctx.config))
+    axes = ctx.config["mesh"]
+    n = int(np.prod(list(axes.values())))
+    mesh = make_mesh(tuple(axes.values()), tuple(axes),
+                     devices=ctx.devices and ctx.devices[:n])
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    specs = fix_param_specs(model.param_specs(), shapes, mesh)
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        shapes, specs)
+    toks = jax.ShapeDtypeStruct((ctx.mix["batch"], ctx.mix["seq_len"]),
+                                jnp.int32, sharding=NamedSharding(mesh, P()))
+    return jax.jit(make_prefill(model)), (params, {"tokens": toks}), mesh
 
 
 class Loop:
@@ -28,25 +55,12 @@ class Loop:
         return self.ctx.batch(i)
 
     def setup(self) -> None:
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
-
-        from repro.launch.mesh import make_mesh
-        from repro.models.model import build_model
         from repro.obs import metrics
-        from repro.parallel.sharding import fix_param_specs
-        from repro.serve.engine import make_prefill
 
-        m, seed = self.dims, self.ctx.seed
-        model = build_model(program_config(self.ctx.config))
-        axes = self.ctx.config["mesh"]
-        self.mesh = make_mesh(tuple(axes.values()), tuple(axes))
-        shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        specs = fix_param_specs(model.param_specs(), shapes, self.mesh)
-        shard = jax.tree.map(lambda s: NamedSharding(self.mesh, s), specs,
-                             is_leaf=lambda s: isinstance(s, P))
-        self.params = make_params(model, m, seed, shard)
-        rep = NamedSharding(self.mesh, P())
+        seed = self.ctx.seed
+        self.fn, (want, batch), self.mesh = program(self.ctx)
+        self.params = make_params(self.ctx, want)
+        rep = batch["tokens"].sharding
         self.inputs = [
             {"tokens": jax.device_put(self._tokens(i), rep)}
             for i in range(self.mix["distinct_batches"])
@@ -55,7 +69,6 @@ class Loop:
             name: metrics.get_metrics().counter(name)
             for name in self.ctx.limits.get("counters", {})
         }
-        self.fn = jax.jit(make_prefill(model))
         self.argmax = jax.jit(lambda lg: jnp.argmax(lg, -1).astype(jnp.int32))
         with jax.sharding.set_mesh(self.mesh):
             out = self.fn(self.params, self.inputs[0])
@@ -86,12 +99,12 @@ class Loop:
                 pending.block_until_ready()
         elapsed = time.perf_counter() - t0
         b, s = self.mix["batch"], self.mix["seq_len"]
-        work = counters.prefill(self.dims, b, s)
+        work = self.ctx.family.prefill(self.dims, b, s)
         finite = bool(jnp.isfinite(self.kept[1]).all())
         return {
             "attempted": calls, "failed": 0 if finite else 1,
             "elapsed": elapsed, "steps": calls,
-            "work": counters.Work(work.flops * calls, work.bytes * calls),
+            "work": Work(work.flops * calls, work.bytes * calls),
             "metrics": {"prefill_tokens_per_s": calls * b * s / elapsed},
         }
 
@@ -109,8 +122,8 @@ class Loop:
                   for i in range(tokens.shape[0])]
         chosen = [jax.device_put(self.chosen[i:i + 1], devs[i % len(devs)])
                   for i in range(tokens.shape[0])]
-        gaps, cgaps = reference.token_gaps(self.dims, self.ctx.seed, blocks,
-                                           chosen, control=control)
+        gaps, cgaps = self.ctx.family.token_gaps(
+            self.dims, self.ctx.seed, blocks, chosen, control=control)
         masks = [np.ones(t.shape, bool) for t in chosen]
         out = gap_numbers(gaps, cgaps, masks, control,
                            self.ctx.limits["limits"]["token_gap"])

@@ -12,9 +12,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import counters, reference, weights
+from chipbench.family import Work, seed_key
 from chipbench.loops import Context, make_params, span, train_numbers
-from chipbench.model import program_config
+
+
+def program(ctx: Context):
+    """``make_train_step`` jitted with its state donated, with the shapes
+    of the state (weights and optimizer moments) and of a batch."""
+    from repro.models.model import build_model
+    from repro.train import optimizer as opt
+    from repro.train.loop import make_train_step
+
+    model = build_model(ctx.family.program_config(ctx.config))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(
+        lambda p: {"params": p, "opt_state": opt.init_state(p)}, params)
+    toks = jax.ShapeDtypeStruct((ctx.mix["batch"], ctx.mix["seq_len"]),
+                                jnp.int32)
+    step = jax.jit(make_train_step(
+        model, opt.OptimizerConfig(**ctx.config["optimizer"])),
+        donate_argnums=0)
+    return step, (state, {"tokens": toks, "labels": toks}), None
 
 
 class Loop:
@@ -29,17 +47,12 @@ class Loop:
         return self.ctx.batch(i)
 
     def setup(self) -> None:
-        from repro.models.model import build_model
         from repro.train import optimizer as opt
-        from repro.train.loop import make_train_step
 
-        m, seed = self.dims, self.ctx.seed
-        model = build_model(program_config(self.ctx.config))
-        params = make_params(model, m, seed)
+        m, seed, fam = self.dims, self.ctx.seed, self.ctx.family
+        self.step, (want, _), _ = program(self.ctx)
+        params = make_params(self.ctx, want["params"])
         state = {"params": params, "opt_state": opt.init_state(params)}
-        self.step = jax.jit(
-            make_train_step(model, opt.OptimizerConfig(**self.opt)),
-            donate_argnums=0)
         n = self.ctx.mix["distinct_batches"]
         self.batches = [
             {"tokens": t, "labels": t}
@@ -48,15 +61,15 @@ class Loop:
         b1 = self.opt["b1"]
         first_grad = jax.jit(lambda st: {
             k: jnp.linalg.norm(
-                weights.program_leaf(st["opt_state"]["m"], k).ravel()
+                fam.program_leaf(st["opt_state"]["m"], k).ravel()
             ) / (1 - b1)
-            for k in weights.leaf_groups(m)})
+            for k in fam.leaf_groups(m)})
         change = jax.jit(lambda st, key: {
             k: jnp.linalg.norm((
-                weights.program_leaf(st["params"], k).astype(jnp.float32)
-                - weights.program_leaf(weights.program_params(m, key), k)
+                fam.program_leaf(st["params"], k).astype(jnp.float32)
+                - fam.program_leaf(fam.program_params(m, key), k)
                 .astype(jnp.float32)).ravel())
-            for k in weights.leaf_groups(m)})
+            for k in fam.leaf_groups(m)})
         # The first three steps go through the window's own call and feed;
         # they compile the step and are what the reference follows.
         self.losses = []
@@ -66,7 +79,7 @@ class Loop:
             if i == 0:
                 self.grad_norms = jax.tree.map(float, first_grad(state))
         self.change_norms = jax.tree.map(
-            float, change(state, weights.seed_key(seed)))
+            float, change(state, seed_key(seed)))
         self.state = state
         self.next = 3
 
@@ -90,13 +103,13 @@ class Loop:
             final = float(pending)
         elapsed = time.perf_counter() - t0
         self.state = state
-        work = counters.train_step(self.dims, b, s)
+        work = self.ctx.family.train_step(self.dims, b, s)
         return {
             "attempted": steps,
             "failed": 0 if np.isfinite(final) else steps,
             "elapsed": elapsed,
             "steps": steps,
-            "work": counters.Work(work.flops * steps, work.bytes * steps),
+            "work": Work(work.flops * steps, work.bytes * steps),
             "metrics": {"train_tokens_per_s": steps * b * s / elapsed},
         }
 
@@ -104,7 +117,7 @@ class Loop:
         self.state = self.step = self.batches = None
 
     def check(self, control: bool = False) -> dict:
-        ref = reference.train_reference(
+        ref = self.ctx.family.train_reference(
             self.dims, self.ctx.seed, [self._batch(i) for i in range(3)],
             self.opt, block=self.ctx.mix.get("reference_block", 1))
         for k in sorted(ref["grad_norms"]):

@@ -12,7 +12,6 @@ call; it is not part of the test suite.
 
 from __future__ import annotations
 
-import functools
 import json
 import os
 import pathlib
@@ -37,87 +36,37 @@ def _memory(compiled) -> dict:
     return out
 
 
-def _sds(tree, sharding):
-    import jax
-
-    return jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
-        tree)
-
-
 def rehearse(cell: str, topo) -> dict:
+    """The cell's program, as its loop builds it, compiled for ``topo``:
+    a mesh of its devices, and arguments without a sharding of their own
+    on its first device."""
     import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, SingleDeviceSharding
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import SingleDeviceSharding
 
-    from chipbench import harness, weights
-    from chipbench.model import Dims, program_config
-    from repro.models.model import build_model
+    import repro.kernels.ops as ops
+    from chipbench import harness, scopes
 
-    c = harness.Files(ROOT).cell(cell)
-    cfg, mix = c["config"], c["traffic"]
-    m = Dims.from_config(cfg)
-    model = build_model(program_config(cfg))
-    shapes = jax.eval_shape(functools.partial(weights.program_params, m),
-                            jax.random.PRNGKey(0))
+    # The kernels pick interpret mode from the default backend (the CPU
+    # here); the described chip needs them compiled.
+    ops._interpret = lambda: False
+    built = scopes.program(harness.Files(ROOT), cell, topo.devices)
+    if built is None:
+        return {"cell": cell, "program": None}
+    fn, args, mesh = built
     one = SingleDeviceSharding(topo.devices[0])
-    loop = mix["loop"]
-    if loop == "train":
-        from repro.train import optimizer as opt
-        from repro.train.loop import make_train_step
-
-        state = jax.eval_shape(
-            lambda p: {"params": p, "opt_state": opt.init_state(p)}, shapes)
-        toks = jax.ShapeDtypeStruct((mix["batch"], mix["seq_len"]),
-                                    jnp.int32, sharding=one)
-        step = jax.jit(make_train_step(
-            model, opt.OptimizerConfig(**cfg["optimizer"])),
-            donate_argnums=0)
-        compiled = step.lower(_sds(state, one),
-                              {"tokens": toks, "labels": toks}).compile()
-    elif loop == "decode":
-        from repro.serve.engine import make_serve_step
-
-        cache = jax.eval_shape(
-            lambda: model.init_cache(mix["batch"], mix["cache_len"]))
-        toks = jax.ShapeDtypeStruct((mix["batch"], 1), jnp.int32,
-                                    sharding=one)
-        pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
-        compiled = jax.jit(make_serve_step(model)).lower(
-            _sds(shapes, one), _sds(cache, one), toks, pos).compile()
+    args = jax.tree.map(
+        lambda a: a if a.sharding is not None else jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), args)
+    if mesh is None:
+        compiled = fn.lower(*args).compile()
     else:
-        import repro.kernels.ops as ops
-        from repro.launch.mesh import make_mesh
-        from repro.parallel.sharding import fix_param_specs
-        from repro.serve.engine import make_prefill
-
-        # The kernels pick interpret mode from the default backend (the
-        # CPU here); the described chip needs them compiled.
-        ops._interpret = lambda: False
-        axes = cfg["mesh"]
-        n = 1
-        for v in axes.values():
-            n *= v
-        mesh = make_mesh(tuple(axes.values()), tuple(axes),
-                         devices=topo.devices[:n])
-        specs = fix_param_specs(model.param_specs(), shapes, mesh)
-        params = jax.tree.map(
-            lambda a, s: jax.ShapeDtypeStruct(
-                a.shape, a.dtype, sharding=NamedSharding(mesh, s)),
-            shapes, specs)
-        toks = jax.ShapeDtypeStruct((mix["batch"], mix["seq_len"]),
-                                    jnp.int32,
-                                    sharding=NamedSharding(mesh, P()))
         with jax.sharding.set_mesh(mesh):
-            compiled = jax.jit(make_prefill(model)).lower(
-                params, {"tokens": toks}).compile()
-        text = compiled.as_text()
-        return {"cell": cell, "memory": _memory(compiled),
-                "tpu_custom_call": text.count("tpu_custom_call"),
-                "all_gather": text.count("all-gather"),
-                "reduce_scatter": text.count("reduce-scatter")}
-    return {"cell": cell, "memory": _memory(compiled)}
+            compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    return {"cell": cell, "memory": _memory(compiled),
+            "tpu_custom_call": text.count("tpu_custom_call"),
+            "all_gather": text.count("all-gather"),
+            "reduce_scatter": text.count("reduce-scatter")}
 
 
 def main(argv=None) -> int:

@@ -109,20 +109,24 @@ def compare(files, cell: str) -> dict:
                                     for s in t[1]})}
 
 
-def tree(tmp: pathlib.Path, backend: str = ""):
+def tree(tmp: pathlib.Path, cell: str, backend: str = ""):
+    """The tiny tree, with ``cell``'s configuration on the overlap
+    ``backend`` where one is given."""
     from chipbench import harness
 
     root, bench = tinytree.make(tmp)
+    files = harness.Files(root, bench)
     if backend:
-        path = bench / "configs" / "yi-9b.json"
+        name = files.cell(cell)["entry"]["config"]
+        path = bench / "configs" / f"{name}.json"
         cfg = json.loads(path.read_text())
         cfg["overlap"]["backend"] = backend
         path.write_text(json.dumps(cfg))
-    return harness.Files(root, bench)
+    return files
 
 
 def main():
-    files = tree(pathlib.Path(sys.argv[1]), *sys.argv[3:])
+    files = tree(pathlib.Path(sys.argv[1]), *sys.argv[2:])
     print(json.dumps(compare(files, sys.argv[2])), flush=True)
 
 

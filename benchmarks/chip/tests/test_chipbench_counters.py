@@ -9,8 +9,10 @@ import pytest
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
-from chipbench import counters, peaks  # noqa: E402
-from chipbench.model import Dims  # noqa: E402
+from chipbench import harness, peaks  # noqa: E402
+
+counters = harness.Files(BENCH.parents[1]).module("families", "dense")
+Dims = counters.Dims
 
 
 def _dims(name):

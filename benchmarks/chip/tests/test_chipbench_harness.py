@@ -35,8 +35,8 @@ def _added_tree(tmp_path):
     """The repository's benchmark files, plus one configuration, one mix,
     one cell and one per-layer metric added as new files and entries."""
     root, bench = tmp_path, tmp_path / "bench"
-    for sub in ("configs", "traffic", "cells", "metrics", "generators",
-                "loops"):
+    for sub in ("configs", "families", "traffic", "cells", "metrics",
+                "generators", "loops"):
         shutil.copytree(BENCH / sub, bench / sub)
     cfg = json.loads((BENCH / "configs/olmo-1b-stage8.json").read_text())
     cfg["model"].update(TINY)
@@ -117,7 +117,7 @@ kind of loop)."""
 
 import time
 
-from chipbench import counters
+from chipbench.family import Work
 
 
 class Loop:
@@ -134,7 +134,7 @@ class Loop:
             n += 1
         elapsed = time.perf_counter() - t0
         return {"attempted": n, "failed": 0, "elapsed": elapsed,
-                "steps": n, "work": counters.Work(),
+                "steps": n, "work": Work(),
                 "metrics": {"batches_per_s": n / elapsed}}
 
     def release(self):
@@ -187,6 +187,191 @@ def test_a_mix_of_a_new_kind_is_added_by_files_alone(tmp_path, loop):
     after = {p: p.read_bytes() for p in BENCH.rglob("*")
              if p.is_file() and "__pycache__" not in p.parts}
     assert after == before
+
+
+FAMILY = '''"""The dense family under another name (a test's added family)."""
+
+import pathlib
+
+from chipbench import harness
+
+_bench = pathlib.Path(__file__).resolve().parents[1]
+dense = harness.Files(_bench, _bench).module("families", "dense")
+
+Dims, dims, program_config = dense.Dims, dense.dims, dense.program_config
+program_params, leaf_groups, program_leaf = (
+    dense.program_params, dense.leaf_groups, dense.program_leaf)
+prefill, decode_batch, train_step = (
+    dense.prefill, dense.decode_batch, dense.train_step)
+token_gaps, train_reference = dense.token_gaps, dense.train_reference
+'''
+
+LOGITS_LOOP = '''"""A closed loop of the model's forward on one device, its argmax
+compared at every position (a test's added kind of loop, with its own
+program)."""
+
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from chipbench.family import Work
+from chipbench.loops import gap_numbers, make_params
+
+
+def program(ctx):
+    from repro.models.model import build_model
+    from repro.serve.engine import make_prefill
+
+    model = build_model(ctx.family.program_config(ctx.config))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((ctx.mix["batch"], ctx.mix["seq_len"]),
+                                jnp.int32)
+    return jax.jit(make_prefill(model)), (params, {"tokens": toks}), None
+
+
+class Loop:
+    def __init__(self, ctx):
+        self.ctx = ctx
+
+    def setup(self):
+        self.fn, (want, _), _ = program(self.ctx)
+        self.params = make_params(self.ctx, want)
+        self.tokens = jnp.asarray(self.ctx.batch(0))
+        self.fn(self.params, {"tokens": self.tokens}).block_until_ready()
+
+    def window(self, seconds):
+        t0, n = time.perf_counter(), 0
+        while n == 0 or time.perf_counter() - t0 < seconds:
+            out = self.fn(self.params, {"tokens": self.tokens})
+            out.block_until_ready()
+            n += 1
+        elapsed = time.perf_counter() - t0
+        self.chosen = np.asarray(jnp.argmax(out, -1))
+        b, s = self.tokens.shape
+        w = self.ctx.family.prefill(self.ctx.dims, b, s)
+        return {"attempted": n, "failed": 0, "elapsed": elapsed,
+                "steps": n, "work": Work(w.flops * n, w.bytes * n),
+                "metrics": {"logits_per_s": n * b * s / elapsed}}
+
+    def release(self):
+        self.params = self.fn = None
+
+    def check(self, control=False):
+        toks = np.asarray(self.tokens)
+        rows = range(len(toks))
+        gaps, cgaps = self.ctx.family.token_gaps(
+            self.ctx.dims, self.ctx.seed,
+            [jnp.asarray(toks[i:i + 1]) for i in rows],
+            [jnp.asarray(self.chosen[i:i + 1]) for i in rows],
+            control=control)
+        return gap_numbers(gaps, cgaps, [np.ones((1, toks.shape[1]), bool)
+                                          for _ in rows], control,
+                           self.ctx.limits["limits"]["token_gap"])
+'''
+
+SCOPE_READER = '''"""attn_ms.logits: device ms per call in the attention scope (a test's
+added scope metric)."""
+
+from chipbench import scopes
+
+SCOPE = "attn"
+
+
+def read(run):
+    return scopes.ms_per_step(run, SCOPE)
+'''
+
+
+def _new_family_checkout(tmp_path):
+    """A copy of the repository's benchmark (BENCHMARK.json and its
+    directory), plus a family, a configuration of it, a mix with a loop
+    of its own, a cell and a scope metric, each with its tiny file where
+    it needs one, added as new files and entries alone."""
+    src = tmp_path / "checkout"
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench = src / spec["paths"][0]
+    shutil.copytree(BENCH, bench,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (bench / "families/toy.py").write_text(FAMILY)
+    cfg = json.loads((bench / "configs/olmo-1b.json").read_text())
+    cfg["family"] = "toy"
+    (bench / "configs/toy-olmo.json").write_text(json.dumps(cfg))
+    (bench / "tests/tiny/configs/toy-olmo.json").write_text(json.dumps(
+        {"model": dict(TINY, dtype="bfloat16")}))
+    (bench / "loops/logits.py").write_text(LOGITS_LOOP)
+    (bench / "traffic/logits-1k.json").write_text(json.dumps(
+        {"loop": "logits", "generator": "uniform_tokens", "batch": 2,
+         "seq_len": 1024}))
+    (bench / "tests/tiny/traffic/logits-1k.json").write_text(json.dumps(
+        {"seq_len": 16}))
+    (bench / "cells/toy-logits.json").write_text(json.dumps(
+        {"limits": {"token_gap": 0.15}}))
+    (bench / "metrics/attn_ms.logits.py").write_text(SCOPE_READER)
+    spec["configs"].append({"name": "toy-olmo", "source": "test",
+                            "file": f"{spec['paths'][0]}/configs/"
+                                    "toy-olmo.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "toy-logits", "config": "toy-olmo",
+                              "traffic": "logits-1k", "chips": 1,
+                              "why": "test"})
+    spec["end_to_end"].append({
+        "name": "logits_per_s", "unit": "tokens/s", "better": "higher",
+        "bound": 0.05, "source": "host_clock", "workloads": ["toy-logits"]})
+    spec["per_layer"].append({
+        "name": "attn_ms.logits", "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "model step",
+        "moves": "logits_per_s", "workloads": ["toy-logits"]})
+    (src / "BENCHMARK.json").write_text(json.dumps(spec))
+    return src
+
+
+def test_a_family_and_a_loop_of_a_new_kind_are_added_by_files_alone(
+        tmp_path):
+    from chipbench import scopes
+
+    before = {p: p.read_bytes() for p in BENCH.rglob("*")
+              if p.is_file() and "__pycache__" not in p.parts}
+    src = _new_family_checkout(tmp_path)
+    assert tinytree.missing(src) == []
+    root, bench = tinytree.make(tmp_path / "tiny", src)
+    files = harness.Files(root, bench)
+    c = files.cell("toy-logits")
+    assert c["config"]["family"] == "toy"
+    assert c["config"]["model"]["d_model"] == TINY["d_model"]
+    assert c["traffic"]["seq_len"] == 16
+    assert files.make_loop(c, 1).ctx.family.dims(c["config"]).d_model == 64
+    res = harness.run_cell(files, "toy-logits", 2**31 + 19, 0.2, False,
+                           require_chip=False, compile_cache=False)
+    assert res["correct"] is True, res["checked"]
+    assert set(res["metrics"]) == {"setup_s", "logits_per_s"}
+    # the scope readers compile the new loop's own program, which holds
+    # the scope its metric reads
+    fn, args, mesh = scopes.program(files, "toy-logits")
+    assert mesh is None and args[1]["tokens"].shape == (2, 16)
+    table = scopes.parse(scopes.compiled_text(fn, args))
+    reader = files.module("metrics", "attn_ms.logits")
+    assert any(reader.SCOPE in t[1] for t in table.values())
+    after = {p: p.read_bytes() for p in BENCH.rglob("*")
+             if p.is_file() and "__pycache__" not in p.parts}
+    assert after == before
+
+
+def test_every_configuration_and_mix_has_its_tiny_file():
+    lacking = tinytree.missing()
+    assert not lacking, f"no tiny cut for: {', '.join(lacking)}"
+
+
+def test_a_configuration_without_a_family_file_is_refused(tmp_path):
+    files = _added_tree(tmp_path)
+    c = files.cell("tiny-train")
+    c["config"]["family"] = "no_such_family"
+    with pytest.raises(SystemExit, match="no families file"):
+        files.make_loop(c, 1)
+    del c["config"]["family"]
+    with pytest.raises(SystemExit, match="no families file"):
+        files.make_loop(c, 1)
 
 
 def test_an_unknown_loop_or_generator_is_refused(tmp_path):

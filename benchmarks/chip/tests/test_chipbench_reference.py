@@ -16,8 +16,11 @@ BENCH = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(BENCH.parents[1] / "src"))
 
-from chipbench import reference, weights  # noqa: E402
-from chipbench.model import Dims  # noqa: E402
+from chipbench import harness  # noqa: E402
+
+dense = harness.Files(BENCH.parents[1]).module("families", "dense")
+reference = weights = dense
+Dims = dense.Dims
 
 # OLMo's kind (non-parametric LayerNorm, tied embeddings, MHA) and Yi's
 # (RMSNorm, GQA, untied output), at widths a CPU test can hold.
@@ -35,8 +38,9 @@ ARCH = {"olmo": "olmo-1b", "yi": "yi-9b"}
 
 
 def _program(kind):
-    from chipbench.model import program_config
     from repro.models.model import build_model
+
+    program_config = dense.program_config
 
     m = KINDS[kind]
     cfg = {"arch": ARCH[kind], "model": dataclasses.asdict(m),

@@ -24,10 +24,23 @@ sys.path.insert(0, str(ROOT / "src"))
 from chipbench import harness, scopes, trace  # noqa: E402
 
 MS = 1_000_000  # ns
-# (cell, overlap backend): each cell's program as the cell runs it, and
-# the prefill program on the XLA schedules in place of the DMA kernel.
-PROGRAMS = [("olmo1b-decode-chat", ""), ("yi9b-prefill-tp4", ""),
-            ("yi9b-prefill-tp4", "xla")]
+
+
+def _programs() -> list:
+    """(cell, overlap backend): each cell of BENCHMARK.json, its program
+    as the cell runs it, and each cell on the Pallas DMA backend again on
+    the XLA schedules in place of the DMA kernel."""
+    files = harness.Files(ROOT)
+    out = []
+    for w in files.spec()["workloads"]:
+        out.append((w["name"], ""))
+        overlap = files.cell(w["name"])["config"].get("overlap") or {}
+        if overlap.get("backend") == "pallas_dma":
+            out.append((w["name"], "xla"))
+    return out
+
+
+PROGRAMS = _programs()
 
 
 @pytest.fixture(scope="module")

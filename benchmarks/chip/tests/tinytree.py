@@ -2,6 +2,12 @@
 a CPU test can run, the mixes shortened, and the limits as the cells
 have them.
 
+Each configuration and each mix has its tiny cut in a file of the same
+name, ``tiny/configs/<config>.json`` and ``tiny/traffic/<mix>.json``,
+whose values replace the file's own (a group of values, such as a
+configuration's ``model``, key by key).  A configuration or mix added to
+BENCHMARK.json brings its tiny file beside it; nothing here names one.
+
 The training cell (``olmo1b-train-2k``) is not in BENCHMARK.json while
 the program's step is at fault at its batch (PERF.md, Open questions);
 its files are kept, and the copy adds it back by entries alone, as the
@@ -13,45 +19,65 @@ import shutil
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH.parents[1]
-
-_OLMO = dict(num_layers=2, d_model=256, num_heads=4, num_kv_heads=4,
-             head_dim=64, d_ff=512, vocab_size=512)
-MODEL = {
-    "olmo-1b-stage8": _OLMO,
-    "olmo-1b": _OLMO,
-    "yi-9b": dict(num_layers=2, d_model=256, num_heads=4, num_kv_heads=2,
-                  head_dim=64, d_ff=512, vocab_size=512),
-}
-MIX = {
-    "lm-2k": dict(batch=4, seq_len=32),
-    "azure-conv": dict(
-        batch=4, groups=2, cache_len=24,
-        prompt_len={"median": 8, "sigma": 0.5},
-        new_tokens={"median": 4, "sigma": 1.0}),
-    "prefill-4k": dict(batch=4, seq_len=32),
-}
+TINY = pathlib.Path("tests") / "tiny"  # under a benchmark's directory
 
 
-def make(root: pathlib.Path, dtype: str = "bfloat16"):
-    """Write the tiny tree under ``root``; returns (root, bench)."""
+def _tiny(bench: pathlib.Path, kind: str) -> dict:
+    return {p.stem: json.loads(p.read_text())
+            for p in sorted((bench / TINY / kind).glob("*.json"))}
+
+
+# The tiny mixes, for tests that size their checks by them.
+MIX = _tiny(BENCH, "traffic")
+
+
+def merged(base: dict, cut: dict) -> dict:
+    """``base`` with the values of ``cut``; a group in both is merged key
+    by key."""
+    out = dict(base)
+    for k, v in cut.items():
+        both = isinstance(v, dict) and isinstance(base.get(k), dict)
+        out[k] = merged(base[k], v) if both else v
+    return out
+
+
+def missing(source: pathlib.Path = ROOT) -> list:
+    """The tiny files that the configurations and mixes of ``source``'s
+    BENCHMARK.json (with the training cell) lack."""
+    spec = with_train_cell(
+        json.loads((source / "BENCHMARK.json").read_text()))
+    bench = source / spec["paths"][0] / TINY
+    want = ([bench / "configs" / f"{c['name']}.json"
+             for c in spec["configs"]]
+            + [bench / "traffic" / f"{w['traffic']}.json"
+               for w in spec["workloads"]])
+    return sorted({str(p.relative_to(source)) for p in want
+                   if not p.is_file()})
+
+
+def make(root: pathlib.Path, source: pathlib.Path = ROOT):
+    """Write the tiny tree of the benchmark at ``source`` (a checkout's
+    root) under ``root``; returns (root, bench)."""
     root = pathlib.Path(root)
+    spec = with_train_cell(
+        json.loads((source / "BENCHMARK.json").read_text()))
+    src = source / spec["paths"][0]
     bench = root / "bench"
-    for sub in ("configs", "traffic", "cells"):
-        (bench / sub).mkdir(parents=True, exist_ok=True)
-    for sub in ("metrics", "generators", "loops"):
-        shutil.copytree(BENCH / sub, bench / sub, dirs_exist_ok=True)
-    spec = with_train_cell(json.loads((ROOT / "BENCHMARK.json").read_text()))
+    shutil.copytree(src, bench, dirs_exist_ok=True,
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    lacking = missing(source)
+    if lacking:
+        raise FileNotFoundError(f"no tiny cut: {', '.join(lacking)}")
+    models, mixes = _tiny(src, "configs"), _tiny(src, "traffic")
     for c in spec["configs"]:
-        d = json.loads((ROOT / c["file"]).read_text())
-        d["model"].update(MODEL[c["name"]], dtype=dtype)
-        (bench / "configs" / f"{c['name']}.json").write_text(json.dumps(d))
+        d = json.loads((src / "configs" / f"{c['name']}.json").read_text())
+        (bench / "configs" / f"{c['name']}.json").write_text(
+            json.dumps(merged(d, models[c["name"]])))
     for w in spec["workloads"]:
         t = json.loads(
-            (BENCH / "traffic" / f"{w['traffic']}.json").read_text())
-        t.update(MIX[w["traffic"]])
+            (src / "traffic" / f"{w['traffic']}.json").read_text())
         (bench / "traffic" / f"{w['traffic']}.json").write_text(
-            json.dumps(t))
-        shutil.copy(BENCH / "cells" / f"{w['name']}.json", bench / "cells")
+            json.dumps(merged(t, mixes[w["traffic"]])))
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     return root, bench
 
