@@ -11,6 +11,7 @@ from repro.configs.base import (
     OverlapConfig,
     ShapeConfig,
     XLSTMConfig,
+    YarnScaling,
 )
 from repro.configs.registry import ARCHS, get_config
 
@@ -18,5 +19,5 @@ __all__ = [
     "SHAPES", "ARCHS", "get_config",
     "EncDecConfig", "Family", "FrontendConfig", "HybridConfig",
     "MambaConfig", "MLAConfig", "ModelConfig", "MoEConfig",
-    "OverlapConfig", "ShapeConfig", "XLSTMConfig",
+    "OverlapConfig", "ShapeConfig", "XLSTMConfig", "YarnScaling",
 ]

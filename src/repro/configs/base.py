@@ -23,15 +23,41 @@ class Family(str, enum.Enum):
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int  # the router's width: experts over the whole layer
     top_k: int
     num_shared_experts: int = 0
     d_ff_expert: int = 0  # per-expert FFN width
     dense_residual_ff: int = 0  # Arctic: dense FFN in parallel with MoE
     every_k_layers: int = 1  # MoE replaces FFN every k-th layer (Jamba: 2)
-    capacity_factor: float = 1.25
+    # Dense layers (FFN width ModelConfig.d_ff) before the first MoE
+    # layer (DeepSeek-V2's first_k_dense_replace).
+    first_dense_layers: int = 0
+    # Renormalise the top-k router weights to sum to 1.
+    norm_topk_prob: bool = True
+    # None: dropless, every (token, held expert) pair is computed.
+    capacity_factor: Optional[float] = 1.25
+    # The experts this layer holds, (first, count), of num_experts; None:
+    # all.  A layer holding a share computes its experts' part of the
+    # output (expert parallelism, without the exchange).
+    held_experts: Optional[tuple[int, int]] = None
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
+
+    @property
+    def held(self) -> tuple[int, int]:
+        return self.held_experts or (0, self.num_experts)
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN RoPE scaling (arXiv:2309.00071), as DeepSeek-V2 sets it."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,6 +69,7 @@ class MLAConfig:
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+    rope_scaling: Optional[YarnScaling] = None  # None: plain RoPE
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,14 +170,16 @@ class ModelConfig:
         return self.head_dim or self.d_model // self.num_heads
 
     def reduced(self) -> "ModelConfig":
-        """Smoke-test variant: 2 layers, d_model <= 512, <= 4 experts."""
+        """Smoke-test variant: 2 layers (after any leading dense ones),
+        d_model <= 512, <= 4 experts (a held share keeps half)."""
         d_model = min(self.d_model, 256)
         heads = min(self.num_heads, 4)
         kv = max(1, min(self.num_kv_heads, heads))
         while heads % kv:
             kv -= 1
+        lead = self.moe.first_dense_layers if self.moe else 0
         changes: dict = dict(
-            num_layers=2,
+            num_layers=2 + lead,
             d_model=d_model,
             num_heads=heads,
             num_kv_heads=kv,
@@ -169,6 +198,10 @@ class ModelConfig:
                 d_ff_expert=min(self.moe.d_ff_expert or 128, 128),
                 dense_residual_ff=(
                     128 if self.moe.dense_residual_ff else 0
+                ),
+                held_experts=(
+                    (0, min(self.moe.num_experts, 4) // 2)
+                    if self.moe.held_experts else None
                 ),
             )
         if self.mla:

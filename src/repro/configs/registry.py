@@ -15,6 +15,7 @@ from repro.configs.base import (
     ModelConfig,
     MoEConfig,
     XLSTMConfig,
+    YarnScaling,
 )
 
 
@@ -58,8 +59,11 @@ def olmo_1b() -> ModelConfig:
 
 
 def deepseek_v2_lite_16b() -> ModelConfig:
-    # [arXiv:2405.04434] DeepSeek-V2-Lite: MLA (kv_lora 512, rope head 64),
-    # 64 routed experts top-6 + 2 shared, expert FFN 1408.
+    # [arXiv:2405.04434; hf:deepseek-ai/DeepSeek-V2-Lite config.json]
+    # MLA (no q compression, kv_lora 512 with its RMSNorm, rope head 64,
+    # YaRN x40 from 4096), one leading dense layer of width 10944, then
+    # 26 MoE layers: 64 routed experts (width 1408) scored by softmax,
+    # greedy top-6 not renormalised, dropless, beside 2 shared experts.
     return ModelConfig(
         name="deepseek-v2-lite-16b",
         family=Family.MOE,
@@ -67,15 +71,22 @@ def deepseek_v2_lite_16b() -> ModelConfig:
         d_model=2048,
         num_heads=16,
         num_kv_heads=16,
-        d_ff=1408,
+        d_ff=10944,
         vocab_size=102400,
+        rope_theta=10000.0,
         mla=MLAConfig(
             kv_lora_rank=512, rope_head_dim=64,
             nope_head_dim=128, v_head_dim=128,
+            rope_scaling=YarnScaling(
+                factor=40.0, original_max_position=4096,
+                beta_fast=32.0, beta_slow=1.0,
+                mscale=0.707, mscale_all_dim=0.707,
+            ),
         ),
         moe=MoEConfig(
             num_experts=64, top_k=6, num_shared_experts=2,
-            d_ff_expert=1408,
+            d_ff_expert=1408, first_dense_layers=1,
+            norm_topk_prob=False, capacity_factor=None,
         ),
         citation="arXiv:2405.04434",
     )

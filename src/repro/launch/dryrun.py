@@ -195,18 +195,18 @@ def extrapolated_collectives(cfg, shape, mesh):
     1-period and 2-period variants, take the per-period delta, scale to
     full depth (collectives never live inside time scans; see counters).
     Returns (collective_bytes, hlo_flops_extrap, hlo_bytes_extrap)."""
-    period = len(
-        __import__("repro.models.model", fromlist=["layer_pattern"])
-        .layer_pattern(cfg)
-    )
-    n_periods = cfg.num_layers // period
+    from repro.models.model import layer_pattern, lead_layers
+
+    period = len(layer_pattern(cfg))
+    lead = lead_layers(cfg)
+    n_periods = (cfg.num_layers - lead) // period
     if n_periods < 2:
         c = _compile(cfg, shape, mesh)
         return _cost_triple(c)[2], None, None
     enc = cfg.encdec
     mk = lambda k: dataclasses.replace(
         cfg,
-        num_layers=k * period,
+        num_layers=lead + k * period,
         scan_layers=False,
         encdec=dataclasses.replace(
             enc, encoder_layers=max(1, k * enc.encoder_layers // n_periods)

@@ -75,13 +75,52 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     )
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, D) rotated pairwise; positions: (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude factor, ``0.1 * mscale * ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_correction_range(head_dim: int, theta: float,
+                          scaling) -> tuple[int, int]:
+    """The frequency indices between which YaRN ramps from the plain to
+    the interpolated frequency: a dimension that turns ``n`` times over
+    the original context sits at ``d ln(L / 2 pi n) / (2 ln theta)``."""
+    def dim(turns):
+        return (head_dim * math.log(
+            scaling.original_max_position / (turns * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+    low = max(math.floor(dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(dim(scaling.beta_slow)), head_dim - 1)
+    return low, high
+
+
+def yarn_frequencies(head_dim: int, theta: float, scaling) -> jax.Array:
+    """YaRN's inverse frequencies: the plain ones below ``low``, those
+    divided by ``factor`` above ``high``, a linear ramp between."""
+    low, high = yarn_correction_range(head_dim, theta, scaling)
+    ramp = jnp.clip(
+        (jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+        / max(high - low, 1e-3), 0.0, 1.0,
+    )
+    plain = rope_frequencies(head_dim, theta)
+    return plain * (1.0 - ramp) + plain / scaling.factor * ramp
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: Optional[jax.Array] = None,
+               mscale: float = 1.0) -> jax.Array:
+    """x: (..., S, H, D) rotated pairwise; positions: (..., S).  ``freqs``
+    replaces the plain inverse frequencies of ``theta`` (YaRN's), and
+    ``mscale`` scales cos and sin."""
     d = x.shape[-1]
-    freqs = rope_frequencies(d, theta)  # (D/2,)
+    if freqs is None:
+        freqs = rope_frequencies(d, theta)  # (D/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
     cos = jnp.cos(angles)[..., None, :]  # (..., S, 1, D/2)
     sin = jnp.sin(angles)[..., None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
@@ -96,8 +135,9 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 _NEG_INF = -1e30
 
 
-def _block_attn(q, k, v, mask):
-    """q: (B,bq,H,Dk); k: (B,bk,KV,Dk); v: (B,bk,KV,Dv); mask: (bq,bk)."""
+def _block_attn(q, k, v, mask, scale=None):
+    """q: (B,bq,H,Dk); k: (B,bk,KV,Dk); v: (B,bk,KV,Dv); mask: (bq,bk).
+    Scores are scaled by ``scale``, else by 1 / sqrt(Dk)."""
     b, bq, h, d = q.shape
     kv = k.shape[2]
     rep = h // kv
@@ -105,7 +145,8 @@ def _block_attn(q, k, v, mask):
     vr = jnp.repeat(v, rep, axis=2)  # (B,bk,H,Dv)
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", q.astype(jnp.float32), kr.astype(jnp.float32)
-    ) / math.sqrt(d)
+    )
+    scores = scores / math.sqrt(d) if scale is None else scores * scale
     scores = jnp.where(mask[None, None], scores, _NEG_INF)
     m = jnp.max(scores, -1)  # (B,H,bq)
     p = jnp.exp(scores - m[..., None])
@@ -124,11 +165,13 @@ def blockwise_attention(
     q_offset: int = 0,
     block_q: int = 512,
     block_k: int = 512,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Memory-O(S*block) exact attention.
 
     q: (B, Sq, H, D); k, v: (B, Sk, KV, D).  ``q_offset`` is the absolute
     position of q[0] relative to k[0] (cross-attention uses causal=False).
+    Scores are scaled by ``scale``, else by 1 / sqrt(D).
     """
     b, sq, h, d = q.shape
     dv = v.shape[-1]
@@ -171,7 +214,7 @@ def blockwise_attention(
             mask &= ((q_offset + qi * block_q + q_pos_base) < q_offset + sq)[
                 :, None
             ]
-            m_b, l_b, o_b = _block_attn(qblk, kblk, vblk, mask)
+            m_b, l_b, o_b = _block_attn(qblk, kblk, vblk, mask, scale)
             m_new = jnp.maximum(m_run, m_b)
             a1 = jnp.exp(m_run - m_new)
             a2 = jnp.exp(m_b - m_new)
@@ -214,11 +257,13 @@ def cache_attention(
     k_cache: jax.Array,
     v_cache: jax.Array,
     valid_len: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Single-token decode attention over one layer's (S, KV, B, D) cache.
 
     q: (B, 1, H, D).  ``valid_len`` - number of leading slots that hold
-    keys (scalar); None where every slot does (cross-attention).
+    keys (scalar); None where every slot does (cross-attention).  Scores
+    are scaled by ``scale``, else by 1 / sqrt(D).
     """
     b, _, h, d = q.shape
     s, kv = k_cache.shape[0], k_cache.shape[1]
@@ -230,7 +275,8 @@ def cache_attention(
     scores = jnp.einsum(
         "bgrd,sgbd->bgrs", qg.astype(jnp.float32),
         k_cache.astype(jnp.float32),
-    ) / math.sqrt(d)
+    )
+    scores = scores / math.sqrt(d) if scale is None else scores * scale
     if valid_len is not None:
         scores = jnp.where(jnp.arange(s) < valid_len, scores, _NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)

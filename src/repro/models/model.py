@@ -4,7 +4,13 @@ A model is a stack of **periods**: the smallest repeating layer pattern
 (dense: 1 layer; Jamba: 8 layers = 1 attention + 7 Mamba with MoE on odd
 layers; xLSTM: 8 = 7 mLSTM + 1 sLSTM; ...).  Periods are scanned with
 ``lax.scan`` over stacked parameters so 80-layer models compile fast at
-512-way SPMD, and each period is optionally rematerialized.
+512-way SPMD, and each period is optionally rematerialized.  An MoE model
+may open with dense layers (``MoEConfig.first_dense_layers``,
+DeepSeek-V2's first_k_dense_replace): they are a stack of their own,
+``params["lead"]`` and ``cache["lead"]``, run before the periods, whose
+parameters and cache are then ``params["layers"]`` and
+``cache["layers"]``.  A model without them has the one stack, and its
+cache is that stack's list.
 
 Interface (used by train/serve/launch):
     model = build_model(config)
@@ -76,6 +82,11 @@ def layer_pattern(cfg: ModelConfig) -> list[LayerSpec]:
             for i in range(period)
         ]
     return [LayerSpec(mixer, "mlp")]
+
+
+def lead_layers(cfg: ModelConfig) -> int:
+    """Dense layers before the periods (DeepSeek-V2: 1)."""
+    return cfg.moe.first_dense_layers if cfg.moe else 0
 
 
 def _dtype(cfg: ModelConfig):
@@ -194,7 +205,8 @@ def _layer_apply(
     elif spec.mixer == "mla":
         y = mla.mla_apply(
             p["attn"], h, cfg.num_heads, cfg.mla,
-            positions=positions, window=_window(cfg),
+            positions=positions, rope_theta=cfg.rope_theta,
+            window=_window(cfg),
         )
     elif spec.mixer == "mamba":
         y = mamba.mamba_apply(p["mixer"], h, cfg.hybrid.mamba)
@@ -262,7 +274,8 @@ def _layer_decode(p, spec: LayerSpec, cfg: ModelConfig, x, cache, layer,
         )
     elif spec.mixer == "mla":
         y, written = mla.mla_decode(
-            p["attn"], h, cache, layer, pos, cfg.num_heads, cfg.mla
+            p["attn"], h, cache, layer, pos, cfg.num_heads, cfg.mla,
+            rope_theta=cfg.rope_theta,
         )
     else:
         state = {k: layers.cache_layer(a, layer)
@@ -313,12 +326,15 @@ class Model:
     def __init__(self, config: ModelConfig):
         self.config = config
         self.pattern = layer_pattern(config)
-        if config.num_layers % len(self.pattern):
+        self.n_lead = lead_layers(config)
+        self.lead_pattern = [LayerSpec(self.pattern[0].mixer, "mlp")]
+        stacked = config.num_layers - self.n_lead
+        if stacked % len(self.pattern):
             raise ValueError(
-                f"{config.name}: {config.num_layers} layers not divisible "
+                f"{config.name}: {stacked} layers not divisible "
                 f"by period {len(self.pattern)}"
             )
-        self.n_periods = config.num_layers // len(self.pattern)
+        self.n_periods = stacked // len(self.pattern)
         self.is_encdec = config.encdec is not None
 
     # ---- init -----------------------------------------------------------
@@ -332,8 +348,13 @@ class Model:
                 jax.random.normal(r[0], (cfg.vocab_size, cfg.d_model)) * std
             ).astype(dt),
             "final_norm": layers.norm_init(cfg.d_model, cfg.norm, dt),
-            "layers": self._init_stack(r[1], cross=self.is_encdec),
+            "layers": self._init_stack(r[1], self.pattern, self.n_periods,
+                                       cross=self.is_encdec),
         }
+        if self.n_lead:
+            params["lead"] = self._init_stack(
+                r[5], self.lead_pattern, self.n_lead, cross=False
+            )
         if not cfg.tie_embeddings:
             params["unembed"] = (
                 jax.random.normal(r[2], (cfg.d_model, cfg.vocab_size)) * std
@@ -347,15 +368,15 @@ class Model:
             )
         return params
 
-    def _init_stack(self, rng, *, cross: bool):
+    def _init_stack(self, rng, pattern, n_periods: int, *, cross: bool):
         def init_period(r):
-            rs = jax.random.split(r, len(self.pattern))
+            rs = jax.random.split(r, len(pattern))
             return [
                 _layer_init(rs[i], s, self.config, cross=cross)
-                for i, s in enumerate(self.pattern)
+                for i, s in enumerate(pattern)
             ]
 
-        rngs = jax.random.split(rng, self.n_periods)
+        rngs = jax.random.split(rng, n_periods)
         periods = [init_period(r) for r in rngs]
         # stack over periods
         return jax.tree.map(lambda *xs: jnp.stack(xs), *periods)
@@ -371,19 +392,22 @@ class Model:
     # ---- sharding specs ---------------------------------------------------
     def param_specs(self) -> dict:
         cfg = self.config
-        stack = [
-            _layer_specs(s, cfg, cross=self.is_encdec) for s in self.pattern
-        ]
-        # prepend scan dim (periods) to every leaf
-        stack = jax.tree.map(
-            lambda sp: P(None, *sp), stack,
-            is_leaf=lambda x: isinstance(x, P),
-        )
+
+        def stack(pattern, cross):
+            # prepend scan dim (periods) to every leaf
+            return jax.tree.map(
+                lambda sp: P(None, *sp),
+                [_layer_specs(s, cfg, cross=cross) for s in pattern],
+                is_leaf=lambda x: isinstance(x, P),
+            )
+
         specs: dict[str, Any] = {
             "embed": P(MODEL_AXIS, None),
             "final_norm": _norm_spec(cfg),
-            "layers": stack,
+            "layers": stack(self.pattern, self.is_encdec),
         }
+        if self.n_lead:
+            specs["lead"] = stack(self.lead_pattern, False)
         if not cfg.tie_embeddings:
             specs["unembed"] = P(None, MODEL_AXIS)
         if self.is_encdec:
@@ -398,13 +422,13 @@ class Model:
         return specs
 
     # ---- forward ----------------------------------------------------------
-    def _run_stack(self, stack_params, x, positions, *, enc_out=None,
-                   causal=True):
+    def _run_stack(self, stack_params, x, positions, pattern, n_periods,
+                   *, enc_out=None, causal=True):
         cfg = self.config
 
         def period_fn(x, period_params):
             aux = jnp.zeros((), jnp.float32)
-            for i, spec in enumerate(self.pattern):
+            for i, spec in enumerate(pattern):
                 x, a = _layer_apply(
                     period_params[i], spec, cfg, x, positions,
                     enc_out=enc_out, causal=causal,
@@ -421,7 +445,7 @@ class Model:
             period_fn = jax.checkpoint(period_fn, policy=policy)
 
         with jax.named_scope("layers"):
-            if cfg.scan_layers and self.n_periods > 1:
+            if cfg.scan_layers and n_periods > 1:
                 def body(x, pp):
                     x, aux = period_fn(x, pp)
                     return x, aux
@@ -430,7 +454,7 @@ class Model:
                 return x, jnp.sum(auxs)
             # unrolled
             aux = jnp.zeros((), jnp.float32)
-            for i in range(self.n_periods):
+            for i in range(n_periods):
                 pp = jax.tree.map(lambda a, i=i: a[i], stack_params)
                 x, a = period_fn(x, pp)
                 aux = aux + a
@@ -479,9 +503,16 @@ class Model:
             x = jnp.concatenate([pe, x], axis=1)
 
         positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
+        if self.n_lead:
+            x, lead_aux = self._run_stack(
+                params["lead"], x, positions, self.lead_pattern, self.n_lead
+            )
         x, aux = self._run_stack(
-            params["layers"], x, positions, enc_out=enc_out
+            params["layers"], x, positions, self.pattern, self.n_periods,
+            enc_out=enc_out,
         )
+        if self.n_lead:
+            aux = aux + lead_aux
         x = layers.apply_norm(params["final_norm"], x, cfg.norm)
         if cfg.frontend is not None and "prefix_embeds" in batch:
             x = x[:, -tokens.shape[1]:]  # logits over the text segment
@@ -523,30 +554,39 @@ class Model:
         cfg = self.config
         dt = _dtype(cfg)
 
-        def period_cache():
+        def stack_cache(pattern, n_periods, cross):
             caches = [
                 _layer_init_cache(s, cfg, batch, cache_len, dt)
-                for s in self.pattern
+                for s in pattern
             ]
-            if self.is_encdec:
+            if cross:
                 shape = layers.kv_cache_shape(_attn_dims(cfg), batch, enc_len)
                 for c in caches:
                     c["cross_k"] = jnp.zeros(shape, dt)
                     c["cross_v"] = jnp.zeros(shape, dt)
-            return caches
+            return jax.tree.map(
+                lambda a: jnp.broadcast_to(a, (n_periods, *a.shape)), caches
+            )
 
-        one = period_cache()
-        return jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (self.n_periods, *a.shape)), one
-        )
+        stack = stack_cache(self.pattern, self.n_periods, self.is_encdec)
+        if not self.n_lead:
+            return stack
+        return {"lead": stack_cache(self.lead_pattern, self.n_lead, False),
+                "layers": stack}
 
     def cache_batch_dims(self, cache):
         """For each leaf of ``cache`` (as ``init_cache`` makes it), the
         dimension that holds the batch, after the periods dimension: the
         K/V caches hold it where their layout puts it, the rest first."""
         kv = 1 + layers.KV_CACHE_BATCH_AXIS
-        return [{name: kv if name in ("k", "v") + _CROSS else 1
-                 for name in c} for c in cache]
+
+        def stack(caches):
+            return [{name: kv if name in ("k", "v") + _CROSS else 1
+                     for name in c} for c in caches]
+
+        if self.n_lead:
+            return {k: stack(v) for k, v in cache.items()}
+        return stack(cache)
 
     def prefill_cross(self, params, cache, enc_frames):
         """Enc-dec: run the encoder and fill per-layer cross K/V."""
@@ -587,30 +627,51 @@ class Model:
         with jax.named_scope("embed"):
             x = params["embed"][tokens].astype(_dtype(cfg))
 
+        with jax.named_scope("layers"):
+            if self.n_lead:
+                x, lead = self._decode_stack(
+                    params["lead"], cache["lead"], self.lead_pattern,
+                    self.n_lead, x, pos,
+                )
+                x, stack = self._decode_stack(
+                    params["layers"], cache["layers"], self.pattern,
+                    self.n_periods, x, pos,
+                )
+                cache = {"lead": lead, "layers": stack}
+            else:
+                x, cache = self._decode_stack(
+                    params["layers"], cache, self.pattern, self.n_periods,
+                    x, pos,
+                )
+        x = layers.apply_norm(params["final_norm"], x, cfg.norm)
+        logits = self._unembed(params, x)
+        return logits, cache
+
+    def _decode_stack(self, stack_params, cache, pattern, n_periods, x,
+                      pos):
+        """One stack's layers of a decode step: (x, its cache)."""
+        cfg = self.config
+
         def body(carry, args):
             x, cache = carry
             pp, layer = args
             cache = list(cache)
-            for i, spec in enumerate(self.pattern):
+            for i, spec in enumerate(pattern):
                 x, cache[i] = _layer_decode(
                     pp[i], spec, cfg, x, cache[i], layer, pos,
                     has_cross="cross_k" in cache[i],
                 )
             return (x, cache), None
 
-        with jax.named_scope("layers"):
-            if cfg.scan_layers and self.n_periods > 1:
-                (x, cache), _ = lax.scan(
-                    body, (x, cache),
-                    (params["layers"], jnp.arange(self.n_periods)),
-                )
-            else:
-                for i in range(self.n_periods):
-                    pp = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-                    (x, cache), _ = body((x, cache), (pp, i))
-        x = layers.apply_norm(params["final_norm"], x, cfg.norm)
-        logits = self._unembed(params, x)
-        return logits, cache
+        if cfg.scan_layers and n_periods > 1:
+            (x, cache), _ = lax.scan(
+                body, (x, cache), (stack_params, jnp.arange(n_periods)),
+            )
+        else:
+            for i in range(n_periods):
+                pp = jax.tree.map(lambda a, i=i: a[i], stack_params)
+                (x, cache), _ = body((x, cache), (pp, i))
+        return x, cache
 
 
 def build_model(config: ModelConfig) -> Model:

@@ -206,10 +206,13 @@ def active_params(cfg) -> float:
         return total
     # subtract the inactive routed experts' share
     e, k = cfg.moe.num_experts, cfg.moe.top_k
+    held = cfg.moe.held[1]
     d, f = cfg.d_model, cfg.moe.d_ff_expert
-    n_moe_layers = cfg.num_layers // cfg.moe.every_k_layers
-    routed = 3.0 * d * f * e * n_moe_layers
-    active_routed = 3.0 * d * f * k * n_moe_layers
+    n_moe_layers = ((cfg.num_layers - cfg.moe.first_dense_layers)
+                    // cfg.moe.every_k_layers)
+    routed = 3.0 * d * f * held * n_moe_layers
+    # a layer holding a share computes its share of the k, in expectation
+    active_routed = 3.0 * d * f * k * held / e * n_moe_layers
     return total - routed + active_routed
 
 

@@ -18,7 +18,7 @@ from __future__ import annotations
 import dataclasses
 
 from repro.configs.base import Family, ModelConfig, ShapeConfig
-from repro.models.model import layer_pattern
+from repro.models.model import layer_pattern, lead_layers
 
 
 @dataclasses.dataclass
@@ -81,7 +81,12 @@ def _gated_mlp(d, ff, tokens) -> Costs:
 def _moe_costs(cfg: ModelConfig, tokens) -> Costs:
     mo, d = cfg.moe, cfg.d_model
     c = _gemm(tokens, mo.num_experts, d)  # router
-    c += mo.top_k * _gated_mlp(d, mo.d_ff_expert, tokens)
+    held = mo.held[1]
+    if held == mo.num_experts:
+        c += mo.top_k * _gated_mlp(d, mo.d_ff_expert, tokens)
+    else:  # the held experts' share of the assignments, in expectation
+        c += (mo.top_k * held / mo.num_experts) * _gated_mlp(
+            d, mo.d_ff_expert, tokens)
     if mo.num_shared_experts:
         c += _gated_mlp(d, mo.d_ff_expert * mo.num_shared_experts, tokens)
     if mo.dense_residual_ff:
@@ -146,28 +151,36 @@ def forward_costs(
     if cfg.sliding_window:
         ctx = min(ctx, cfg.sliding_window)
     pattern = layer_pattern(cfg)
-    n_periods = cfg.num_layers // len(pattern)
+    lead = lead_layers(cfg)
+    n_periods = (cfg.num_layers - lead) // len(pattern)
     tokens = b * s
+
+    def add_layer(c: Costs, spec) -> Costs:
+        if spec.mixer == "attn":
+            c += _attn_costs(cfg, b, s, ctx, decode=decode)
+        elif spec.mixer == "mla":
+            c += _mla_costs(cfg, b, s, ctx, decode=decode)
+        elif spec.mixer == "mamba":
+            c += _mamba_costs(cfg, b, s, decode=decode)
+        elif spec.mixer == "mlstm":
+            c += _mlstm_costs(cfg, b, s, decode=decode)
+        else:
+            c += _slstm_costs(cfg, b, s, decode=decode)
+        if spec.ffn == "mlp":
+            c += _gated_mlp(cfg.d_model, cfg.d_ff, tokens)
+        elif spec.ffn == "moe":
+            c += _moe_costs(cfg, tokens)
+        # norms / residuals
+        return c + Costs(8.0 * tokens * cfg.d_model,
+                         6.0 * tokens * cfg.d_model * 2)
+
     per_period = Costs()
     for spec in pattern:
-        if spec.mixer == "attn":
-            per_period += _attn_costs(cfg, b, s, ctx, decode=decode)
-        elif spec.mixer == "mla":
-            per_period += _mla_costs(cfg, b, s, ctx, decode=decode)
-        elif spec.mixer == "mamba":
-            per_period += _mamba_costs(cfg, b, s, decode=decode)
-        elif spec.mixer == "mlstm":
-            per_period += _mlstm_costs(cfg, b, s, decode=decode)
-        else:
-            per_period += _slstm_costs(cfg, b, s, decode=decode)
-        if spec.ffn == "mlp":
-            per_period += _gated_mlp(cfg.d_model, cfg.d_ff, tokens)
-        elif spec.ffn == "moe":
-            per_period += _moe_costs(cfg, tokens)
-        # norms / residuals
-        per_period += Costs(8.0 * tokens * cfg.d_model,
-                            6.0 * tokens * cfg.d_model * 2)
+        per_period = add_layer(per_period, spec)
     total = n_periods * per_period
+    if lead:  # the dense layers before the periods
+        total += lead * add_layer(
+            Costs(), dataclasses.replace(pattern[0], ffn="mlp"))
     # embed + unembed
     total += Costs(0.0, tokens * cfg.d_model * 2)
     total += _gemm(tokens, cfg.vocab_size, cfg.d_model)

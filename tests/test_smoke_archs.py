@@ -64,14 +64,21 @@ def _expected_cache_writes(model, cache) -> dict:
     """Trace-time counts of the decode step's cache writes: a row in
     place for each attention / MLA leaf, a whole rewrite for each
     recurrent leaf, nothing for the read-only cross-attention K/V; once
-    per traced layer (a scan traces its body once)."""
-    traced = 1 if model.config.scan_layers and model.n_periods > 1 else (
-        model.n_periods)
+    per traced layer (a scan traces its body once), in each stack (the
+    leading dense layers, where a model has them, and the periods)."""
+    stacks = [(model.pattern, model.n_periods, cache)]
+    if model.n_lead:
+        stacks = [(model.lead_pattern, model.n_lead, cache["lead"]),
+                  (model.pattern, model.n_periods, cache["layers"])]
     want = {"token": 0, "state": 0}
-    for spec, leaves in zip(model.pattern, cache):
-        n = sum(1 for name in leaves if not name.startswith("cross_"))
-        want["token" if spec.mixer in ("attn", "mla") else "state"] += n
-    return {k: v * traced for k, v in want.items()}
+    for pattern, n_periods, stack in stacks:
+        traced = 1 if model.config.scan_layers and n_periods > 1 else (
+            n_periods)
+        for spec, leaves in zip(pattern, stack):
+            n = sum(1 for name in leaves if not name.startswith("cross_"))
+            kind = "token" if spec.mixer in ("attn", "mla") else "state"
+            want[kind] += n * traced
+    return want
 
 
 @pytest.mark.parametrize("arch", sorted(ARCHS))
